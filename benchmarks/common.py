@@ -34,15 +34,19 @@ def print_rows(name: str, rows: list[dict]):
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: float = 1200.0):
-    """Run python code with N forced host devices; expects a final JSON line."""
+    """Run python code with N forced host devices; expects a final JSON line.
+
+    The child is a CPU emulation by design: it is pinned to the CPU
+    backend, so on a TPU host it never competes for the chip."""
     prog = textwrap.dedent(f"""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = \
             "--xla_force_host_platform_device_count={devices}"
         import json
         {textwrap.indent(textwrap.dedent(code), '        ').lstrip()}
     """)
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=timeout, env=env)
     if p.returncode != 0:

@@ -413,6 +413,8 @@ dense RMAT graphs."""
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     text = build()
     with open("EXPERIMENTS.md", "w") as f:
         f.write(text)

@@ -600,6 +600,8 @@ def check_chaos(out: dict) -> list[str]:
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="rmat16-16")
     ap.add_argument("--requests", type=int,

@@ -142,6 +142,8 @@ def bench_record(out: dict) -> dict:
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="rmat16-16")
     ap.add_argument("--algo", choices=("bfs", "cc", "sssp"), default="bfs",

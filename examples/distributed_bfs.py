@@ -23,6 +23,8 @@ from repro.graph import get_dataset
 
 def main():
     n_dev = jax.device_count()
+    dev = jax.devices()[0]
+    device = f"{dev.platform} {dev.device_kind}"   # labels every rate
     ds = get_dataset("rmat18-16")
     deg = np.diff(ds.csr.indptr)
     root = int(np.argmax(deg))
@@ -48,7 +50,7 @@ def main():
         dt = time.perf_counter() - t0
         trav = int(deg[np.minimum(lev, 1 << 30) < (1 << 30)].sum())
         print(f"  {dispatch:6s}/{crossbar:6s}: ok, {dt:.2f}s, "
-              f"{trav/dt/1e9:.4f} GTEPS (CPU), {eng.last_stats}")
+              f"{trav/dt/1e9:.4f} GTEPS ({device}), {eng.last_stats}")
 
     print("crossbar resource model (paper §IV-D):",
           f"64x64 full = {full_crossbar_fifos(64)} FIFOs,",
@@ -70,7 +72,7 @@ def main():
     dt = time.perf_counter() - t0
     trav = count_traversed_edges(deg, levels)
     print(f"  MS-BFS batch=32: ok, {dt:.2f}s, {trav/dt/1e9:.4f} aggregate "
-          f"GTEPS (CPU), {eng.last_stats}")
+          f"GTEPS ({device}), {eng.last_stats}")
 
 
 if __name__ == "__main__":

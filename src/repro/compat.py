@@ -1,120 +1,54 @@
-"""JAX version-compat layer (supported: 0.4.37 through current).
+"""Thin wrappers over the JAX sharding APIs the repo uses (JAX 0.9).
 
-The repo targets one API surface; this module resolves it against whatever
-JAX is installed.  Everything that moved between the 0.4.x experimental
-namespaces and the newer top-level APIs is imported from here, never from
-``jax`` directly:
+Call sites import these names from here rather than from ``jax``:
 
-* ``shard_map``       — ``jax.shard_map`` (new) or
-                        ``jax.experimental.shard_map.shard_map`` (0.4.x);
-                        the ``check_vma`` kwarg maps onto 0.4.x ``check_rep``.
-* ``make_mesh``       — passes ``axis_types=(AxisType.Auto, ...)`` only when
-                        the installed JAX has ``jax.sharding.AxisType``.
-* ``get_abstract_mesh`` — the ambient trace-time mesh.  New JAX reads its
-                        abstract-mesh context; 0.4.x falls back to the mesh
-                        installed by :func:`use_mesh` (or, failing that, the
-                        classic ``with mesh:`` thread-local physical mesh).
+* ``shard_map``       — ``jax.shard_map``; ``check_vma=None`` keeps the
+                        library default.
+* ``axis_size``       — ``jax.lax.axis_size``.
+* ``make_mesh``       — ``jax.make_mesh`` with every axis ``Auto``.
+* ``get_abstract_mesh`` — the ambient trace-time mesh, or None.
 * ``use_mesh``        — context manager the step builders use to make a
                         physical mesh ambient at trace time.
 * ``constraint_sharding`` — what to hand ``with_sharding_constraint`` for a
-                        PartitionSpec: the bare spec under an abstract-mesh
-                        context (new JAX), a ``NamedSharding`` bound to the
-                        physical mesh on 0.4.x (where bare specs require the
-                        legacy resource environment).
+                        PartitionSpec: a ``NamedSharding`` for a physical
+                        mesh, the bare spec under an abstract-mesh context.
 """
 from __future__ import annotations
 
 import contextlib
-import threading
 
 import jax
 
-try:                                    # newer JAX: top-level export
-    from jax import shard_map as _shard_map_new
-except ImportError:                     # 0.4.x: experimental namespace
-    _shard_map_new = None
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-except ImportError:                     # future JAX may drop the old path
-    _shard_map_exp = None
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-HAS_ABSTRACT_MESH_CTX = hasattr(jax.sharding, "get_abstract_mesh")
-
-
-def jax_version() -> tuple[int, ...]:
-    return tuple(int(p) for p in jax.__version__.split(".")[:3]
-                 if p.isdigit())
+axis_size = jax.lax.axis_size
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-stable shard_map.  ``check_vma=None`` keeps the library
-    default; an explicit bool maps onto 0.4.x ``check_rep``."""
-    if _shard_map_new is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-    if _shard_map_exp is None:          # pragma: no cover - defensive
-        raise ImportError("no shard_map implementation in this JAX")
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-
-def axis_size(name) -> int:
-    """``jax.lax.axis_size`` (newer JAX) with a 0.4.x fallback:
-    ``psum(1, name)`` constant-folds to the bound axis size."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
+    """``jax.shard_map``; ``check_vma=None`` keeps the library default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis_types when the API supports them."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if HAS_AXIS_TYPES:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-_AMBIENT = threading.local()            # 0.4.x fallback mesh context
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)
 
 
 def get_abstract_mesh():
     """The ambient trace-time mesh, or None when outside any mesh context.
-
-    New JAX returns the AbstractMesh from its context; on 0.4.x this is the
-    physical mesh installed by :func:`use_mesh` (or a legacy ``with mesh:``
-    block).  Callers only rely on ``axis_names`` / ``shape``, which both
-    mesh flavors provide.
-    """
-    if HAS_ABSTRACT_MESH_CTX:
-        m = jax.sharding.get_abstract_mesh()
-        return m if m is not None and m.axis_names else None
-    m = getattr(_AMBIENT, "mesh", None)
-    if m is not None:
-        return m
-    from jax._src import mesh as _mesh_lib
-    pm = _mesh_lib.thread_resources.env.physical_mesh
-    return None if pm.empty else pm
+    Callers only rely on ``axis_names`` / ``shape``."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m is not None and m.axis_names else None
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
     """Make physical ``mesh`` ambient for sharding hints at trace time."""
-    if HAS_ABSTRACT_MESH_CTX:
-        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-            yield
-        return
-    prev = getattr(_AMBIENT, "mesh", None)
-    _AMBIENT.mesh = mesh
-    try:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         yield
-    finally:
-        _AMBIENT.mesh = prev
 
 
 def constraint_sharding(mesh, spec):

@@ -88,7 +88,10 @@ class DistributedBFS:
         self.wl = self.vl // bitmap.WORD_BITS  # local bitmap words
         self.n_pad = pg.num_vertices_padded
         spec = NamedSharding(mesh, P(self.axes))
-        put = lambda x: jax.device_put(jnp.asarray(x), spec)
+        # numpy straight onto its sharding: each device receives only its
+        # own shards (staging through jnp.asarray would put every whole
+        # array on one device first)
+        put = lambda x: jax.device_put(np.asarray(x), spec)
         # Shard-stacked graph arrays: leading axis Q splits across devices.
         self.out_indptr = put(pg.out_indptr.astype(np.int32))
         self.out_indices = put(pg.out_indices)
@@ -176,9 +179,9 @@ class DistributedBFS:
         frontier[shard, local // 32] = np.uint32(1) << (local % 32)
         level = np.full((q, vl), int(INF), np.int32)
         level[shard, local] = 0
-        return (jax.device_put(jnp.asarray(frontier), s),
-                jax.device_put(jnp.asarray(frontier), s),   # visited
-                jax.device_put(jnp.asarray(level), s))
+        return (jax.device_put(frontier, s),
+                jax.device_put(frontier, s),                 # visited
+                jax.device_put(level, s))
 
     # -- jitted sharded programs -----------------------------------------
     # Every shard_map block is [k, ...]: k PE rows on this device.
@@ -494,9 +497,9 @@ class DistributedBFS:
             shard, local = int(r) // vl, int(r) % vl
             frontier[shard, local, i // 32] |= np.uint32(1) << (i % 32)
             level[shard, local, i] = 0
-        return (jax.device_put(jnp.asarray(frontier), s),
-                jax.device_put(jnp.asarray(frontier), s),   # seen
-                jax.device_put(jnp.asarray(level), s))
+        return (jax.device_put(frontier, s),
+                jax.device_put(frontier, s),                 # seen
+                jax.device_put(level, s))
 
     # -- driver -----------------------------------------------------------
     def run(self, root: int, max_iters: int | None = None):
@@ -622,7 +625,9 @@ class DistributedBFS:
                                     pg.num_vertices, int(sv[SV_NU]))
             is_push = mode == PUSH
             need = int(sv[SV_MF]) if is_push else int(sv[SV_MU])
-            while budget * self.k < need:
+            # ``budget`` is per shard and ``need`` spans all q shards: start
+            # at an even share, and let overflow deepen a skewed shard
+            while budget * self.q < need:
                 budget *= 2
             while True:
                 kind = "push_b" if is_push else "pull_b"

@@ -46,7 +46,7 @@ SV_NF, SV_MF, SV_MU, SV_NU, SV_TOTAL, SV_OVERFLOW, SV_COUNT = range(7)
 @partial(jax.tree_util.register_dataclass,
          data_fields=("out_indptr", "out_indices", "in_indptr", "in_indices",
                       "out_src", "in_child", "out_deg", "in_deg",
-                      "in_seg_first", "in_seg_end"),
+                      "in_seg_end"),
          meta_fields=("n", "n_pad"))
 @dataclasses.dataclass(frozen=True)
 class LocalGraph:
@@ -57,7 +57,7 @@ class LocalGraph:
     Degrees are precomputed once at build time (they feed the per-level
     scheduler stats; re-deriving them with ``jnp.diff`` every level was
     pure waste), as are the CSC segment descriptors the scan-based pull
-    propagate uses (``in_seg_first``/``in_seg_end``).
+    propagate uses (``in_child`` as the segment ids, ``in_seg_end``).
     """
 
     n: int
@@ -70,7 +70,6 @@ class LocalGraph:
     in_child: jax.Array     # int32[E] edge-parallel CSC rows (children)
     out_deg: jax.Array      # int32[n_pad] stored out-degrees
     in_deg: jax.Array       # int32[n_pad] stored in-degrees
-    in_seg_first: jax.Array  # bool[E]  e starts a child's in-list
     in_seg_end: jax.Array    # int32[n_pad] last in-edge per child (-1: none)
 
 
@@ -85,22 +84,27 @@ def build_local_graph(csr: CSRGraph, csc: CSRGraph) -> LocalGraph:
     out_ptr = pad_ptr(csr.indptr)
     in_ptr = pad_ptr(csc.indptr)
     in_deg = np.diff(in_ptr)
-    e_in = int(csc.indices.shape[0])
-    in_first = np.zeros(e_in, dtype=bool)
-    in_first[in_ptr[:-1][in_deg > 0]] = True
     in_end = np.where(in_deg > 0, in_ptr[1:] - 1, -1)
+    out_indptr = jnp.asarray(out_ptr.astype(np.int32))
+    out_indices = jnp.asarray(csr.indices)
+    out_src = jnp.asarray(edge_sources(csr))
+    out_deg = jnp.asarray(np.diff(out_ptr).astype(np.int32))
+    if csc is csr:
+        # a symmetric graph is its own transpose: one copy on the device
+        in_indptr, in_indices, in_child, in_deg_dev = (
+            out_indptr, out_indices, out_src, out_deg)
+    else:
+        in_indptr = jnp.asarray(in_ptr.astype(np.int32))
+        in_indices = jnp.asarray(csc.indices)
+        in_child = jnp.asarray(edge_sources(csc))
+        in_deg_dev = jnp.asarray(in_deg.astype(np.int32))
 
     return LocalGraph(
         n=n, n_pad=n_pad,
-        out_indptr=jnp.asarray(out_ptr.astype(np.int32)),
-        out_indices=jnp.asarray(csr.indices),
-        in_indptr=jnp.asarray(in_ptr.astype(np.int32)),
-        in_indices=jnp.asarray(csc.indices),
-        out_src=jnp.asarray(edge_sources(csr)),
-        in_child=jnp.asarray(edge_sources(csc)),
-        out_deg=jnp.asarray(np.diff(out_ptr).astype(np.int32)),
-        in_deg=jnp.asarray(in_deg.astype(np.int32)),
-        in_seg_first=jnp.asarray(in_first),
+        out_indptr=out_indptr, out_indices=out_indices,
+        in_indptr=in_indptr, in_indices=in_indices,
+        out_src=out_src, in_child=in_child,
+        out_deg=out_deg, in_deg=in_deg_dev,
         in_seg_end=jnp.asarray(in_end.astype(np.int32)),
     )
 
@@ -431,15 +435,28 @@ def count_traversed_edges(out_deg: np.ndarray, levels: np.ndarray) -> int:
 
 
 def bfs_oracle(csr: CSRGraph, root: int) -> np.ndarray:
-    """Pure-python BFS (Algorithm 1) — the correctness oracle."""
-    from collections import deque
-    level = np.full(csr.num_vertices, int(INF), dtype=np.int64)
+    """Level-synchronous numpy BFS over the CSR arrays — the correctness
+    oracle.  Returns int64[n] hop levels from ``root`` (INF = unreached);
+    it shares no code with the engines."""
+    n = csr.num_vertices
+    indptr = np.asarray(csr.indptr, np.int64)
+    unreached = int(INF)
+    level = np.full(n, unreached, dtype=np.int64)
     level[root] = 0
-    q = deque([root])
-    while q:
-        v = q.popleft()
-        for u in csr.neighbors(v):
-            if level[u] == int(INF):
-                level[u] = level[v] + 1
-                q.append(int(u))
+    frontier = np.asarray([root], np.int64)
+    lvl = 0
+    while frontier.size:
+        starts = indptr[frontier]
+        degs = indptr[frontier + 1] - starts
+        total = int(degs.sum())
+        if total == 0:
+            break
+        # edge offsets of every frontier vertex's out-list, concatenated
+        run_start = np.cumsum(degs) - degs
+        offs = np.repeat(starts - run_start, degs) + np.arange(total)
+        hit = np.zeros(n, dtype=bool)
+        hit[csr.indices[offs]] = True
+        frontier = np.flatnonzero(hit & (level == unreached))
+        lvl += 1
+        level[frontier] = lvl
     return level

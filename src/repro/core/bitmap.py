@@ -200,23 +200,32 @@ def _scatter_or_rows(words: jax.Array, row_idx: jax.Array,
     return jax.lax.bitcast_convert_type(bytes_, jnp.uint32).reshape(r, nw)
 
 
-def segment_or_rows(msg: jax.Array, first: jax.Array) -> jax.Array:
+def segment_or_rows(msg: jax.Array, seg: jax.Array) -> jax.Array:
     """Inclusive segmented OR-scan over rows of packed words.
 
-    ``msg`` is uint32[E, nw] (one packed source-mask per edge), ``first`` is
-    bool[E] marking the first edge of each contiguous segment.  Returns
-    scan[E, nw] where scan[e] = OR of msg over e's segment up to e — read
-    the last slot of each segment for the per-segment OR.  This is how the
-    pull direction reduces each vertex's in-list without any scatter: CSC
-    edges are already grouped by child, so the segment boundaries are
-    static (``LocalGraph.in_seg_first`` / ``in_seg_end``).
+    ``msg`` is uint32[E, nw] (one packed source-mask per edge), ``seg`` is
+    int[E], the segment of each row: rows of one segment are contiguous
+    and carry equal ids.  Returns scan[E, nw] where scan[e] = OR of msg
+    over e's segment up to e — read the last slot of each segment for the
+    per-segment OR.  This is how the pull direction reduces each vertex's
+    in-list without any scatter: CSC edges are already grouped by child,
+    so the child of each edge (``LocalGraph.in_child``) is the segment id.
+
+    Hillis–Steele doubling: ceil(log2 E) passes, each ORing in the row
+    ``2^k`` back when it lies in the same segment.  Every pass is a static
+    shift, which the TPU compiler handles in seconds at any E, whereas
+    ``lax.associative_scan`` costs it compile memory and time in
+    proportion to E (about 4 GB and 40 s per million rows on v5e).
     """
-    def op(a, b):
-        av, af = a
-        bv, bf = b
-        return jnp.where(bf[..., None], bv, av | bv), af | bf
-    v, _ = jax.lax.associative_scan(op, (msg, first), axis=0)
-    return v
+    e = msg.shape[0]
+    x = msg
+    shift = 1
+    while shift < e:
+        same = seg[shift:] == seg[:-shift]
+        prev = jnp.where(same[:, None], x[:-shift], jnp.uint32(0))
+        x = jnp.concatenate([x[:shift], x[shift:] | prev])
+        shift *= 2
+    return x
 
 
 def any_rows(words: jax.Array) -> jax.Array:

@@ -72,17 +72,19 @@ def _shard_lists(indptr: np.ndarray, indices: np.ndarray, n: int, q: int,
     """Slice the neighbor-list arrays of each shard's owned vertices."""
     shard_indptr = np.zeros((q, vl + 1), dtype=np.int64)
     shard_lists = []
+    deg = np.diff(indptr)
     for s in range(q):
         owned = _owned(s, n, q, vl, scheme)
-        degs = np.diff(indptr)[owned] if owned.size else np.zeros(0, np.int64)
+        degs = deg[owned]
         ptr = np.zeros(vl + 1, dtype=np.int64)
         np.cumsum(degs, out=ptr[1: 1 + owned.size])
         if owned.size < vl:
             ptr[1 + owned.size:] = ptr[owned.size]
         shard_indptr[s] = ptr
-        chunks = [indices[indptr[v]: indptr[v + 1]] for v in owned]
-        shard_lists.append(np.concatenate(chunks) if chunks else
-                           np.zeros(0, np.int32))
+        # edge offsets of every owned vertex's list, concatenated in order
+        offs = (np.repeat(indptr[owned] - ptr[:owned.size], degs)
+                + np.arange(ptr[owned.size]))
+        shard_lists.append(indices[offs])
     emax = max((x.size for x in shard_lists), default=0)
     emax = ((emax + pad_multiple - 1) // pad_multiple) * pad_multiple
     emax = max(emax, pad_multiple)

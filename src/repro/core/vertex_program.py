@@ -277,7 +277,7 @@ def _propagate_pull_scan(g: LocalGraph, frontier_w):
     if g.in_indices.shape[0] == 0:
         return jnp.zeros_like(frontier_w)
     msg = frontier_w[g.in_indices]                  # [E, nw] packed gather
-    scan = bitmap.segment_or_rows(msg, g.in_seg_first)
+    scan = bitmap.segment_or_rows(msg, g.in_child)
     return jnp.where((g.in_seg_end >= 0)[:, None],
                      scan[jnp.maximum(g.in_seg_end, 0)], jnp.uint32(0))
 
@@ -315,7 +315,7 @@ def _propagate_pull_sparse(g: LocalGraph, frontier_w, seen_w, nb: int,
     valid = e < total
     parent = g.in_indices[jnp.where(valid, eidx, 0)]
     msg = jnp.where(valid[:, None], frontier_w[parent], jnp.uint32(0))
-    scan = bitmap.segment_or_rows(msg, e == start)
+    scan = bitmap.segment_or_rows(msg, owner_c)
     # one segment end per active vertex -> unique scatter targets, so a
     # plain row set (mode="drop" for the pad slots) lands the per-vertex OR
     endpos = jnp.clip(cum - 1, 0, budget - 1)
@@ -1040,9 +1040,10 @@ class ConnectedComponentsRunner(VertexProgramRunner):
     def from_csr(cls, csr, **kw) -> "ConnectedComponentsRunner":
         """Build from a (possibly directed) CSR: symmetrize, then wire up."""
         from repro.core.bfs_local import build_local_graph
-        from repro.graph.csr import symmetrize_csr, transpose_csr
+        from repro.graph.csr import symmetrize_csr
         sym = symmetrize_csr(csr)
-        return cls(build_local_graph(sym, transpose_csr(sym)), **kw)
+        # a symmetrized graph is its own transpose: one device copy
+        return cls(build_local_graph(sym, sym), **kw)
 
     def _finalize(self, res: VertexProgramResult,
                   roots: np.ndarray) -> VertexProgramResult:

@@ -44,11 +44,12 @@ def csr_from_edges(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         keep = src != dst
         src, dst = src[keep], dst[keep]
     if dedup and src.size:
-        key = src * num_vertices + dst
-        _, uniq = np.unique(key, return_index=True)
-        src, dst = src[uniq], dst[uniq]
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
+        # unique keys come back sorted, i.e. ordered by (src, dst)
+        key = np.unique(src * num_vertices + dst)
+        src, dst = key // num_vertices, key % num_vertices
+    else:
+        order = np.lexsort((dst, src))
+        src, dst = src[order], dst[order]
     counts = np.bincount(src, minlength=num_vertices)
     indptr = np.zeros(num_vertices + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])

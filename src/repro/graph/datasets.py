@@ -1,22 +1,26 @@
 """Named dataset registry mirroring the paper's Table I.
 
-Real-world SNAP/LAW graphs are not downloadable in this offline container, so
-the registry exposes the paper's full RMAT suite (exact scales/degrees) plus
-reduced stand-ins for the four real-world graphs with matched vertex-count /
-average-degree *ratios* (documented in EXPERIMENTS.md).  Every entry is
-generated deterministically and cached on disk.
+Real-world SNAP/LAW graphs are not downloaded, so the registry exposes the
+paper's full RMAT suite (exact scales/degrees) plus reduced stand-ins for
+the four real-world graphs with matched vertex-count / average-degree
+*ratios* (documented in EXPERIMENTS.md).  Every entry is generated
+deterministically from its seed and, by default, cached on disk under the
+temporary directory (``REPRO_GRAPH_CACHE`` overrides it).
 """
 from __future__ import annotations
 
 import dataclasses
 import os
+import tempfile
 
 import numpy as np
 
 from repro.graph.csr import CSRGraph, csr_from_edges, symmetrize_edges, transpose_csr
 from repro.graph.generators import rmat_edges
 
-CACHE_DIR = os.environ.get("REPRO_GRAPH_CACHE", "/tmp/repro_graphs")
+def _cache_dir() -> str:
+    return (os.environ.get("REPRO_GRAPH_CACHE")
+            or os.path.join(tempfile.gettempdir(), "repro_graphs"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,21 +74,35 @@ class Dataset:
 
 
 def get_dataset(name: str, seed: int = 1, cache: bool = True) -> Dataset:
+    """Generate (or load the cached copy of) dataset ``name``.
+
+    An undirected graph is symmetrized, so its CSC is its CSR: the same
+    object is returned for both, and ``build_local_graph`` then keeps one
+    copy of the arrays on the device.
+    """
     spec = DATASETS[name]
-    path = os.path.join(CACHE_DIR, f"{name}-s{seed}.npz")
+    cache_dir = _cache_dir()
+    path = os.path.join(cache_dir, f"{name}-s{seed}.npz")
     if cache and os.path.exists(path):
         z = np.load(path)
         csr = CSRGraph(int(z["n"]), z["indptr"], z["indices"])
-        csc = CSRGraph(int(z["n"]), z["t_indptr"], z["t_indices"])
+        csc = (CSRGraph(int(z["n"]), z["t_indptr"], z["t_indices"])
+               if spec.directed else csr)
         return Dataset(spec, csr, csc)
     src, dst = rmat_edges(spec.scale, spec.edge_factor, seed=seed)
     if not spec.directed:
         src, dst = symmetrize_edges(src, dst)
     n = 1 << spec.scale
     csr = csr_from_edges(src, dst, n)
-    csc = transpose_csr(csr)
+    csc = transpose_csr(csr) if spec.directed else csr
     if cache:
-        os.makedirs(CACHE_DIR, exist_ok=True)
-        np.savez_compressed(path, n=n, indptr=csr.indptr, indices=csr.indices,
-                            t_indptr=csc.indptr, t_indices=csc.indices)
+        os.makedirs(cache_dir, exist_ok=True)
+        arrays = dict(n=n, indptr=csr.indptr, indices=csr.indices)
+        if spec.directed:
+            arrays.update(t_indptr=csc.indptr, t_indices=csc.indices)
+        # write then rename: concurrent test workers never read a torn file
+        tmp = f"{path}.{os.getpid()}.tmp"
+        with open(tmp, "wb") as f:
+            np.savez_compressed(f, **arrays)
+        os.replace(tmp, path)
     return Dataset(spec, csr, csc)

@@ -14,7 +14,9 @@ pass (the "double pump BRAM: two ops per cycle" analogue), and the popcount
 rides along for free instead of a second reduction pass.
 
 Grid: 1-D over row-tiles of a [rows, 128] word array; BlockSpec keeps
-(block_rows, 128) word tiles in VMEM (8 KiB at block_rows=16).
+(block_rows, 128) word tiles in VMEM (8 KiB at block_rows=16).  The
+popcount accumulates in an SMEM block: Mosaic stores no scalars to VMEM.
+Interpret mode follows the backend (``repro.kernels.mode``).
 """
 from __future__ import annotations
 
@@ -23,6 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mode import interpret_mode
 
 
 def _kernel(cand_ref, vis_ref, nf_ref, vout_ref, cnt_ref):
@@ -57,7 +62,7 @@ def _kernel_batch(cand_ref, vis_ref, nf_ref, vout_ref, cnt_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def bitmap_update_batch(cand: jax.Array, visited: jax.Array,
-                        block_rows: int = 16, interpret: bool = True):
+                        block_rows: int = 16, interpret: bool | None = None):
     """Fused frontier update over a BATCH of bit-planes.
 
     cand/visited: uint32[batch, rows, 128] — one plane per 32-source word of
@@ -77,19 +82,20 @@ def bitmap_update_batch(cand: jax.Array, visited: jax.Array,
         grid=grid,
         in_specs=[blk, blk],
         out_specs=[blk, blk,
-                   pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0))],
+                   pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0),
+                                memory_space=pltpu.SMEM)],
         out_shape=[
             jax.ShapeDtypeStruct((b, rows, 128), jnp.uint32),
             jax.ShapeDtypeStruct((b, rows, 128), jnp.uint32),
             jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cand, visited)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def bitmap_update(cand: jax.Array, visited: jax.Array,
-                  block_rows: int = 16, interpret: bool = True):
+                  block_rows: int = 16, interpret: bool | None = None):
     """Fused frontier update on uint32[rows, 128] word arrays.
 
     Returns (new_frontier, visited_out, new_count).
@@ -102,11 +108,12 @@ def bitmap_update(cand: jax.Array, visited: jax.Array,
         _kernel,
         grid=grid,
         in_specs=[blk, blk],
-        out_specs=[blk, blk, pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=[blk, blk, pl.BlockSpec((1, 1), lambda i: (0, 0),
+                                          memory_space=pltpu.SMEM)],
         out_shape=[
             jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
             jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(cand, visited)

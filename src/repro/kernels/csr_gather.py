@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import interpret_mode
+
 
 def _kernel(page_ids_ref, edges_ref, out_ref):
     del page_ids_ref  # consumed by the index_map (scalar prefetch)
@@ -30,7 +32,7 @@ def _kernel(page_ids_ref, edges_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_pages(edges_paged: jax.Array, page_ids: jax.Array,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """Gather pages of the edge array: out[i] = edges_paged[page_ids[i]].
 
     edges_paged: int32[num_pages, page]  (edge array viewed as pages)
@@ -49,5 +51,5 @@ def gather_pages(edges_paged: jax.Array, page_ids: jax.Array,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, page), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(page_ids, edges_paged)

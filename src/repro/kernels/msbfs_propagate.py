@@ -18,16 +18,17 @@ currency (the win GraphScale/ScalaBFS get from packed BRAM bitmaps).
 
 Two layouts share the kernel body structure:
 
-* ``msbfs_propagate_planes`` — the whole-VMEM variant: the edge index
-  arrays are scalar-prefetched (SMEM, like the paged-gather page table);
-  the frontier/seen/candidate plane arrays live whole in VMEM across the
-  1-D grid over edge chunks (the output BlockSpecs map every grid step to
-  block (0, 0), so the accumulator persists between steps on TPU's
-  sequential grid).  Each chunk runs a fori_loop of read-modify-write row
-  updates — the per-edge loop is the literal analogue of the PE's
-  one-edge-per-cycle P2 stage.  The last grid step applies P3 in place.
-  VMEM bound: 4 plane arrays of (n_rows+1) * nw words (~1 MB at |V|=64k,
-  B=32), so it dies around |V|≈64k–1M depending on the batch.
+* ``msbfs_propagate_planes`` — the whole-VMEM variant: the frontier/seen/
+  candidate plane arrays live whole in VMEM across the 1-D grid over edge
+  chunks (the output BlockSpecs map every grid step to block (0, 0), so
+  the accumulator persists between steps on TPU's sequential grid), and
+  each step streams one chunk of the ``src``/``tgt`` index arrays into
+  SMEM, where the per-edge loop reads them as scalars.  Each chunk runs a
+  fori_loop of read-modify-write row updates — the per-edge loop is the
+  literal analogue of the PE's one-edge-per-cycle P2 stage.  The last
+  grid step applies P3 in place.  VMEM bound: the 4 plane arrays plus
+  P3's values, whose ``nw``-word rows Mosaic pads to 128 lanes, so only
+  small graphs fit (``ops.propagate_plan`` sizes it).
 
 * ``msbfs_propagate_planes_tiled`` — the row-partitioned variant for
   HBM-scale graphs (the software analogue of ScalaBFS's 32 pseudo-
@@ -36,20 +37,25 @@ Two layouts share the kernel body structure:
   edge list by target tile (``ops._bucket_edges_by_tile``) and pre-gathers
   each edge's frontier word into a message stream, so the kernel never
   holds the frontier: per grid step it sees ONE seen/candidate tile plus
-  one ``block_edges``-sized slice of that tile's message segment.  The
+  one ``block_edges``-sized chunk of that tile's message words and target
+  rows, both streamed flat into SMEM (a ``[block_edges, nw]`` VMEM block
+  would pad each edge's row to 128 lanes, in HBM as well).  The
   ``chunk_tile`` scalar-prefetch array drives the BlockSpec index_maps —
   consecutive chunks of the same tile revisit the same output block, so
   the candidate accumulator persists across a tile's chunk run exactly
   like the whole-VMEM grid, while Pallas's pipeline double-buffers the
-  streamed message chunks against it.  P3 fires once per tile, at its
-  last chunk.
+  streamed chunks against it.  P3 fires once per tile, at its last chunk.
 
-Under the interpret emulator (the CPU CI story) both kernels swap the
-per-edge RMW loop for a one-call vectorized chunk scatter with identical
-semantics (``_chunk_scatter``) — the emulator traces every loop
-iteration, which serializes graph500-class edge streams into minutes;
-the sequential loop remains the compiled-TPU body (force either with
-``vector_scatter=``).
+Both kernels write their discovery popcount to a ``(1, 1)`` SMEM block:
+Mosaic stores no scalars to VMEM.
+
+Interpret mode follows the backend (``repro.kernels.mode``): compiled on
+a TPU, the Pallas interpreter elsewhere.  Under the interpreter both
+kernels swap the per-edge RMW loop for a one-call vectorized chunk
+scatter with identical semantics (``_chunk_scatter``) — the emulator
+traces every loop iteration, which serializes graph500-class edge streams
+into minutes; the sequential loop remains the compiled-TPU body (force
+either with ``vector_scatter=``).
 
 The pure-jnp oracle with identical semantics is
 ``repro.core.bitmap._scatter_or_rows`` (see ``kernels.ref``); callers
@@ -65,6 +71,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mode import interpret_mode
 
 # Cross-plane merge ops for the scatter accumulation (the vertex-program
 # ``combine``).  "or" is the bit-plane merge every shipped program uses;
@@ -97,6 +105,8 @@ def _chunk_scatter(acc, rows, msgs, op: str):
     return bitmap._scatter_or_rows(acc, rows, msgs)
 
 
+
+
 def _kernel(src_ref, tgt_ref, frontier_ref, seen_ref, new_ref, vout_ref,
             cnt_ref, *, block_edges: int, op: str, vector_scatter: bool):
     combine = _COMBINE[op]
@@ -106,21 +116,15 @@ def _kernel(src_ref, tgt_ref, frontier_ref, seen_ref, new_ref, vout_ref,
     def _init():
         new_ref[...] = jnp.zeros_like(new_ref[...])
 
-    base = step * block_edges
-
     if vector_scatter:
-        s = pl.load(src_ref, (pl.ds(base, block_edges),))
-        t = pl.load(tgt_ref, (pl.ds(base, block_edges),))
-        new_ref[...] = _chunk_scatter(new_ref[...], t,
-                                      frontier_ref[...][s], op)
+        new_ref[...] = _chunk_scatter(new_ref[...], tgt_ref[...],
+                                      frontier_ref[...][src_ref[...]], op)
     else:
         def body(i, carry):
-            e = base + i
-            s = src_ref[e]
-            t = tgt_ref[e]
-            msg = pl.load(frontier_ref, (pl.ds(s, 1), slice(None)))
-            cur = pl.load(new_ref, (pl.ds(t, 1), slice(None)))
-            pl.store(new_ref, (pl.ds(t, 1), slice(None)), combine(cur, msg))
+            s = src_ref[i]
+            t = tgt_ref[i]
+            msg = frontier_ref[pl.ds(s, 1), :]
+            new_ref[pl.ds(t, 1), :] = combine(new_ref[pl.ds(t, 1), :], msg)
             return carry
 
         jax.lax.fori_loop(0, block_edges, body, 0)
@@ -136,12 +140,23 @@ def _kernel(src_ref, tgt_ref, frontier_ref, seen_ref, new_ref, vout_ref,
                                 .astype(jnp.int32))
 
 
+def _edge_block(block_edges: int, index_map):
+    """One chunk of a flat per-edge array, streamed into SMEM."""
+    return pl.BlockSpec((block_edges,), index_map,
+                        memory_space=pltpu.SMEM)
+
+
+def _count_block(index_map):
+    return pl.BlockSpec((1, 1), index_map, memory_space=pltpu.SMEM)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_edges", "interpret", "op",
                                     "vector_scatter"))
 def msbfs_propagate_planes(frontier: jax.Array, seen: jax.Array,
                            src: jax.Array, tgt: jax.Array,
-                           block_edges: int = 1024, interpret: bool = True,
+                           block_edges: int = 1024,
+                           interpret: bool | None = None,
                            op: str = "or",
                            vector_scatter: bool | None = None):
     """Fused gather/scatter-combine/P3 over packed plane words.
@@ -151,6 +166,8 @@ def msbfs_propagate_planes(frontier: jax.Array, seen: jax.Array,
         point at row ``n_rows - 1`` and contribute nothing to the count.
     src/tgt: int32[m] in [0, n_rows), m a multiple of ``block_edges``.
     op: cross-plane merge for the scatter accumulation ("or" | "max").
+    interpret: None (default) follows the backend (see
+        :func:`repro.kernels.mode.interpret_mode`).
     vector_scatter: None (default) = vectorize the chunk scatter exactly
         when interpreting (see :func:`_chunk_scatter`); pass True/False
         to force either body.
@@ -161,34 +178,28 @@ def msbfs_propagate_planes(frontier: jax.Array, seen: jax.Array,
     """
     if op not in _COMBINE:
         raise ValueError(f"op must be one of {sorted(_COMBINE)}, got {op!r}")
+    interpret = interpret_mode(interpret)
     if vector_scatter is None:
         vector_scatter = interpret
     n_rows, nw = frontier.shape
     m = src.shape[0]
     assert m % block_edges == 0, (m, block_edges)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(m // block_edges,),
-        in_specs=[
-            pl.BlockSpec((n_rows, nw), lambda i, s, t: (0, 0)),
-            pl.BlockSpec((n_rows, nw), lambda i, s, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_rows, nw), lambda i, s, t: (0, 0)),
-            pl.BlockSpec((n_rows, nw), lambda i, s, t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, s, t: (0, 0)),
-        ],
-    )
+    plane = pl.BlockSpec((n_rows, nw), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(_kernel, block_edges=block_edges, op=op,
                           vector_scatter=vector_scatter),
-        grid_spec=grid_spec,
+        grid=(m // block_edges,),
+        in_specs=[_edge_block(block_edges, lambda i: (i,)),
+                  _edge_block(block_edges, lambda i: (i,)),
+                  plane, plane],
+        out_specs=[plane, plane, _count_block(lambda i: (0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((n_rows, nw), jnp.uint32),
             jax.ShapeDtypeStruct((n_rows, nw), jnp.uint32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="msbfs_propagate",
     )(src, tgt, frontier, seen)
 
 
@@ -201,8 +212,9 @@ def _tiled_kernel(chunk_tile_ref, tgt_ref, seen_ref, msg_ref, new_ref,
     nondecreasing, so a tile's chunks are a contiguous grid run and the
     candidate block (``new_ref``) persists across that run.  The first
     chunk of a run zeroes the accumulator, the last applies P3 for the
-    whole tile — between them only the message chunk changes, which is
-    what the Pallas pipeline double-buffers against the resident tile.
+    whole tile — between them only the message and target chunks change,
+    which is what the Pallas pipeline double-buffers against the resident
+    tile.
     """
     combine = _COMBINE[op]
     step = pl.program_id(0)
@@ -221,18 +233,22 @@ def _tiled_kernel(chunk_tile_ref, tgt_ref, seen_ref, msg_ref, new_ref,
     def _init_tile():
         new_ref[...] = jnp.zeros_like(new_ref[...])
 
-    base = step * block_edges
     row0 = tile * tile_rows
+    nw = new_ref.shape[1]
 
     if vector_scatter:
-        t = pl.load(tgt_ref, (pl.ds(base, block_edges),)) - row0
-        new_ref[...] = _chunk_scatter(new_ref[...], t, msg_ref[...], op)
+        new_ref[...] = _chunk_scatter(new_ref[...], tgt_ref[...] - row0,
+                                      msg_ref[...].reshape(-1, nw), op)
     else:
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, nw), 1)
+
         def body(i, carry):
-            t = tgt_ref[base + i] - row0      # tile-local target row
-            msg = pl.load(msg_ref, (pl.ds(i, 1), slice(None)))
-            cur = pl.load(new_ref, (pl.ds(t, 1), slice(None)))
-            pl.store(new_ref, (pl.ds(t, 1), slice(None)), combine(cur, msg))
+            t = tgt_ref[i] - row0             # tile-local target row
+            # the edge's nw message words, scalars from SMEM, as one row
+            msg = jnp.zeros((1, nw), jnp.uint32)
+            for w in range(nw):
+                msg = jnp.where(lanes == w, msg_ref[i * nw + w], msg)
+            new_ref[pl.ds(t, 1), :] = combine(new_ref[pl.ds(t, 1), :], msg)
             return carry
 
         jax.lax.fori_loop(0, block_edges, body, 0)
@@ -254,21 +270,24 @@ def _tiled_kernel(chunk_tile_ref, tgt_ref, seen_ref, msg_ref, new_ref,
 def msbfs_propagate_planes_tiled(seen: jax.Array, msg: jax.Array,
                                  tgt: jax.Array, chunk_tile: jax.Array,
                                  tile_rows: int, block_edges: int = 1024,
-                                 interpret: bool = True, op: str = "or",
+                                 interpret: bool | None = None,
+                                 op: str = "or",
                                  vector_scatter: bool | None = None):
     """Row-tiled fused scatter-combine/P3 over pre-gathered messages.
 
     seen: uint32[R, nw] packed plane words, R a multiple of ``tile_rows``
         (pad rows must be all-ones so they never count as discoveries).
-    msg: uint32[L, nw] message stream, L = NC * block_edges — edge e's
-        frontier word, already gathered and bucketed so chunk c holds only
-        edges of tile ``chunk_tile[c]`` (pad slots carry msg = 0, the
-        combine identity for both "or" and "max").
+    msg: uint32[L * nw] message stream, L = NC * block_edges — edge e's
+        nw frontier words at ``msg[e * nw:(e + 1) * nw]``, already gathered
+        and bucketed so chunk c holds only edges of tile ``chunk_tile[c]``
+        (pad slots carry msg = 0, the combine identity for both "or" and
+        "max").  It is flat so that its SMEM chunks carry no lane padding.
     tgt: int32[L] GLOBAL target rows; tgt[e] must lie inside chunk
         e // block_edges's tile (pad slots point at the tile's first row).
     chunk_tile: int32[NC] nondecreasing tile id per chunk, covering every
         tile of ``seen`` at least once (empty tiles get one pad chunk so
         their P3 still runs).
+    interpret: None (default) follows the backend.
     vector_scatter: None (default) = vectorize the chunk scatter exactly
         when interpreting (see :func:`_chunk_scatter`).
 
@@ -277,25 +296,24 @@ def msbfs_propagate_planes_tiled(seen: jax.Array, msg: jax.Array,
     """
     if op not in _COMBINE:
         raise ValueError(f"op must be one of {sorted(_COMBINE)}, got {op!r}")
+    interpret = interpret_mode(interpret)
     if vector_scatter is None:
         vector_scatter = interpret
     n_rows, nw = seen.shape
     assert n_rows % tile_rows == 0, (n_rows, tile_rows)
     num_chunks = chunk_tile.shape[0]
-    assert msg.shape[0] == num_chunks * block_edges, (
-        msg.shape, num_chunks, block_edges)
+    assert msg.shape == (num_chunks * block_edges * nw,), (
+        msg.shape, num_chunks, block_edges, nw)
+    tile = pl.BlockSpec((tile_rows, nw), lambda i, ct: (ct[i], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(num_chunks,),
         in_specs=[
-            pl.BlockSpec((tile_rows, nw), lambda i, ct, t: (ct[i], 0)),
-            pl.BlockSpec((block_edges, nw), lambda i, ct, t: (i, 0)),
+            _edge_block(block_edges, lambda i, ct: (i,)),
+            tile,
+            _edge_block(block_edges * nw, lambda i, ct: (i,)),
         ],
-        out_specs=[
-            pl.BlockSpec((tile_rows, nw), lambda i, ct, t: (ct[i], 0)),
-            pl.BlockSpec((tile_rows, nw), lambda i, ct, t: (ct[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, ct, t: (0, 0)),
-        ],
+        out_specs=[tile, tile, _count_block(lambda i, ct: (0, 0))],
     )
     return pl.pallas_call(
         functools.partial(_tiled_kernel, block_edges=block_edges,
@@ -308,4 +326,5 @@ def msbfs_propagate_planes_tiled(seen: jax.Array, msg: jax.Array,
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="msbfs_propagate_tiled",
     )(chunk_tile, tgt, seen, msg)

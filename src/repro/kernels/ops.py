@@ -1,11 +1,9 @@
 """Jit'd wrappers that connect the Pallas kernels to the BFS engine.
 
-``interpret=True`` everywhere in this container (CPU); on a real TPU the
-same calls run compiled (set REPRO_PALLAS_INTERPRET=0).
+Interpret mode follows the backend (``repro.kernels.mode``): the same
+calls run compiled on a TPU and in the Pallas interpreter on the CPU.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -17,44 +15,50 @@ from repro.kernels.msbfs_propagate import (msbfs_propagate_planes,
                                            msbfs_propagate_planes_tiled)
 from repro.kernels.pull_spmv import pull_spmv_blocks
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+# VMEM budget for one propagate call's plane working set: the scoped VMEM
+# a TPU kernel gets by default (16 MiB on v5e).  Above it
+# ``msbfs_propagate`` switches to the row-tiled kernel.
+PROPAGATE_VMEM_BYTES = 16 * 1024 * 1024
 
-# VMEM budget for one propagate call's plane working set.  The whole-VMEM
-# kernel keeps 4 plane arrays resident (frontier/seen/new/vout); above this
-# budget ``msbfs_propagate`` switches to the row-tiled kernel.  ~2 MiB
-# leaves headroom (of TPU's ~16 MiB VMEM) for the double-buffered message
-# stream and the scalar-prefetch arrays.
-PROPAGATE_VMEM_BYTES = int(os.environ.get("REPRO_PROPAGATE_VMEM_BYTES",
-                                          2 * 1024 * 1024))
+# Largest streamed edge chunk.  The whole-VMEM kernel streams two int32
+# index chunks into SMEM and the tiled kernel a target chunk plus nw
+# message words per edge, each double-buffered; SMEM holds 1 MiB.
+MAX_BLOCK_EDGES = 4096
+
+LANES = 128
+
+
+def _row_bytes(nw: int) -> int:
+    """VMEM bytes of one ``[rows, nw]`` uint32 plane row: Mosaic tiles the
+    minor dimension by 128 lanes, so a row of nw <= 128 words takes 512."""
+    return 4 * (-(-nw // LANES) * LANES)
 
 
 def _plane_footprint_bytes(n_rows: int, nw: int) -> int:
-    """Whole-VMEM kernel working set: 4 plane arrays incl. the trash row."""
-    return 4 * (n_rows + 1) * nw * 4
+    """Whole-VMEM kernel working set, incl. the trash row: the four plane
+    arrays plus the P3 values Mosaic keeps in scoped VMEM, about eight
+    lane-padded rows per vertex."""
+    return 8 * (n_rows + 1) * _row_bytes(nw)
 
 
 def _auto_tile_rows(nw: int, vmem_bytes: int) -> int:
-    """Tile-size rule: the tiled kernel holds ~8 row-tile-sized buffers
-    (seen + new + vout tiles, their pipeline double-buffers, and slack for
-    the streamed message chunks), so budget 32*nw bytes per row and round
-    down to the 8-row sublane multiple (int32 min tile is (8, 128))."""
-    return max((vmem_bytes // (32 * nw)) // 8 * 8, 8)
+    """Tile-size rule: the tiled kernel's P3 holds about six tile-sized
+    values in scoped VMEM beside the resident tiles, so budget eight
+    lane-padded rows per tile row and round down to the 8-row sublane
+    multiple (int32 min tile is (8, 128))."""
+    return max((vmem_bytes // (8 * _row_bytes(nw))) // 8 * 8, 8)
 
 
-def _auto_block_edges(m: int, nw: int, vmem_bytes: int | None = None) -> int:
-    """Edge-chunk length for the streamed message blocks.
+def _auto_block_edges(m: int, nw: int) -> int:
+    """Edge-chunk length for the streamed index/message blocks.
 
-    Two pressures.  The grid runs one step per chunk, so a fixed
-    1024-edge chunk at graph500-class budgets (m ~ 16M edges per pull
-    level on rmat20) means tens of thousands of grid steps — pure
-    pipeline overhead, and interpret mode inlines every step at trace
-    time.  The chunk therefore grows with m, targeting <= 256 real-edge
-    steps.  Against that, one streamed msg block (block_edges * nw * 4
-    bytes) must stay a small fraction (1/8) of the VMEM budget so it can
-    double-buffer beside the resident plane tiles.  Always a multiple of
-    the 1024 floor, so sub-1024 budgets share one compiled shape."""
-    vmem = PROPAGATE_VMEM_BYTES if vmem_bytes is None else vmem_bytes
-    cap = max((vmem // (8 * 4 * nw)) // 1024 * 1024, 1024)
+    The grid runs one step per chunk, so a fixed small chunk at
+    graph500-class budgets means tens of thousands of grid steps — pure
+    pipeline overhead, and interpret mode runs every step.  The chunk
+    therefore grows with m, targeting <= 256 real-edge steps, up to the
+    SMEM bound ``MAX_BLOCK_EDGES / nw``.  Always a multiple of the 1024
+    floor, so sub-1024 budgets share one compiled shape."""
+    cap = max((MAX_BLOCK_EDGES // nw) // 1024 * 1024, 1024)
     need = -(-(-(-m // 256)) // 1024) * 1024
     return int(min(max(need, 1024), cap))
 
@@ -135,7 +139,7 @@ def _bucket_edges_by_tile(msg: jax.Array, tgt: jax.Array, ok: jax.Array,
 
 def _propagate_tiled(seen_w: jax.Array, msg: jax.Array, tgt: jax.Array,
                      ok: jax.Array, tile_rows: int, block_edges: int,
-                     interpret: bool, op: str):
+                     interpret: bool | None, op: str):
     """Shared tiled-path tail: pad rows to a tile multiple, bucket, run."""
     n, nw = seen_w.shape
     t_ = -(-n // tile_rows)
@@ -148,8 +152,8 @@ def _propagate_tiled(seen_w: jax.Array, msg: jax.Array, tgt: jax.Array,
     sm, st, ct = _bucket_edges_by_tile(msg, tgt, ok, t_, tile_rows,
                                        block_edges)
     new, vout, cnt = msbfs_propagate_planes_tiled(
-        seen_w, sm, st, ct, tile_rows=tile_rows, block_edges=block_edges,
-        interpret=interpret, op=op)
+        seen_w, sm.reshape(-1), st, ct, tile_rows=tile_rows,
+        block_edges=block_edges, interpret=interpret, op=op)
     return new[:n], vout[:n], cnt[0, 0]
 
 
@@ -172,8 +176,6 @@ def msbfs_propagate(frontier_w: jax.Array, seen_w: jax.Array,
     streamed edge-chunk length — one grid step each.
     Returns (new, seen_out, new_count).
     """
-    if interpret is None:
-        interpret = INTERPRET
     n, nw = frontier_w.shape
     m = src.shape[0]
     if m == 0:
@@ -223,8 +225,6 @@ def msbfs_propagate_msgs(seen_w: jax.Array, msg: jax.Array, tgt: jax.Array,
     kernel; ``tile_rows`` defaults to the auto rule of
     :func:`propagate_plan`.  Returns (new, seen_out, new_count).
     """
-    if interpret is None:
-        interpret = INTERPRET
     n, nw = seen_w.shape
     m = tgt.shape[0]
     if m == 0:
@@ -261,8 +261,7 @@ def fused_frontier_update(cand_words: jax.Array, visited_words: jax.Array):
     pad = rows_pad * 128 - w
     c2 = jnp.pad(cand_words, (0, pad)).reshape(rows_pad, 128)
     v2 = jnp.pad(visited_words, (0, pad)).reshape(rows_pad, 128)
-    nf, vo, cnt = bitmap_update(c2, v2, block_rows=block_rows,
-                                interpret=INTERPRET)
+    nf, vo, cnt = bitmap_update(c2, v2, block_rows=block_rows)
     return (nf.reshape(-1)[:w], vo.reshape(-1)[:w], cnt[0, 0])
 
 
@@ -277,8 +276,7 @@ def fused_frontier_update_batch(cand_words: jax.Array,
     pad = rows_pad * 128 - w
     c2 = jnp.pad(cand_words, ((0, 0), (0, pad))).reshape(g, rows_pad, 128)
     v2 = jnp.pad(visited_words, ((0, 0), (0, pad))).reshape(g, rows_pad, 128)
-    nf, vo, cnt = bitmap_update_batch(c2, v2, block_rows=block_rows,
-                                      interpret=INTERPRET)
+    nf, vo, cnt = bitmap_update_batch(c2, v2, block_rows=block_rows)
     return (nf.reshape(g, -1)[:, :w], vo.reshape(g, -1)[:, :w],
             cnt.reshape(g))
 
@@ -315,7 +313,7 @@ def read_neighbor_pages(edges: jax.Array, page_ids: jax.Array, page: int):
     edges is the flat int32 edge array (padded to a page multiple).
     """
     paged = edges.reshape(-1, page)
-    return gather_pages(paged, page_ids, interpret=INTERPRET)
+    return gather_pages(paged, page_ids)
 
 
 def pull_spmv(blocks, block_row, block_col, frontier, num_row_blocks: int):
@@ -324,6 +322,5 @@ def pull_spmv(blocks, block_row, block_col, frontier, num_row_blocks: int):
         [jnp.ones((1,), jnp.int32),
          (block_row[1:] != block_row[:-1]).astype(jnp.int32)])
     acc = pull_spmv_blocks(blocks, block_row, block_col, row_first, frontier,
-                           num_row_blocks=num_row_blocks,
-                           interpret=INTERPRET)
+                           num_row_blocks=num_row_blocks)
     return acc > 0

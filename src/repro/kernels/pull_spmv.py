@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import interpret_mode
+
 
 def _kernel(brow_ref, bcol_ref, first_ref, blocks_ref, f_ref, out_ref):
     i = pl.program_id(0)
@@ -43,7 +45,7 @@ def _kernel(brow_ref, bcol_ref, first_ref, blocks_ref, f_ref, out_ref):
 def pull_spmv_blocks(blocks: jax.Array, block_row: jax.Array,
                      block_col: jax.Array, row_first: jax.Array,
                      frontier: jax.Array, num_row_blocks: int,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Block-sparse boolean SpMV on the MXU.
 
     blocks:    bf16[nb, B, B]   0/1 adjacency tiles (CSC orientation:
@@ -71,5 +73,5 @@ def pull_spmv_blocks(blocks: jax.Array, block_row: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_row_blocks, b, lanes),
                                        jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_row, block_col, row_first, blocks, frontier)

@@ -1,4 +1,6 @@
 import os
+# a CPU emulation of 512 devices by design: never take a TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -253,7 +255,8 @@ def run_all(out_dir: str, timeout: float = 3000.0) -> int:
         t0 = time.time()
         try:
             p = subprocess.run(cmd, capture_output=True, text=True,
-                               timeout=timeout)
+                               timeout=timeout,
+                               env=dict(os.environ, JAX_PLATFORMS="cpu"))
         except subprocess.TimeoutExpired:
             print(f"[{i+1}/{len(cells)}] TIMEOUT {os.path.basename(path)}",
                   flush=True)

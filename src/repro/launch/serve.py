@@ -96,10 +96,13 @@ def greedy_decode(arch: str, reduced: bool, batch: int, prompt_len: int,
     }
 
 
-def build_engine(graph: str, *, algo: str = "bfs",
+def build_engine(graph, *, algo: str = "bfs",
                  distributed: bool | None = None, pes_per_device: int = 2,
                  sparse_pull: bool = False):
     """Build a vertex-program query engine with the graph device-resident.
+
+    ``graph`` is a dataset name (``repro.graph.DATASETS``) or a
+    ``repro.graph.datasets.Dataset`` the caller already generated.
 
     ``algo``: "bfs" | "cc" | "sssp" (the shipped vertex programs — CC
     symmetrizes the graph first, components being an undirected notion).
@@ -121,7 +124,7 @@ def build_engine(graph: str, *, algo: str = "bfs",
     from repro.graph import get_dataset, symmetrize_csr
 
     program = get_program(algo)
-    ds = get_dataset(graph)
+    ds = get_dataset(graph) if isinstance(graph, str) else graph
     csr, csc = ds.csr, ds.csc
     if program.undirected:
         csr = symmetrize_csr(csr)
@@ -339,6 +342,8 @@ def _integrity_summary(stats: dict) -> dict:
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--reduced", action="store_true")

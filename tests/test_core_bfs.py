@@ -121,3 +121,42 @@ def test_levels_are_valid_bfs_levels(small):
         if 0 < lev[v] < INF:
             parents = csc.neighbors(v)
             assert (lev[parents] == lev[v] - 1).any()
+
+
+def _bfs_loop(csr, root):
+    """Per-vertex queue BFS (Algorithm 1), the loop form ``bfs_oracle``
+    replaced: kept here as the oracle's own reference."""
+    from collections import deque
+    inf = int(np.iinfo(np.int32).max)
+    level = np.full(csr.num_vertices, inf, dtype=np.int64)
+    level[root] = 0
+    q = deque([root])
+    while q:
+        v = q.popleft()
+        for u in csr.neighbors(v):
+            if level[u] == inf:
+                level[u] = level[v] + 1
+                q.append(int(u))
+    return level
+
+
+@pytest.mark.parametrize("name", ["tiny-16-4", "small-12-8",
+                                  "directed-rmat10-4"])
+def test_vectorized_oracle_matches_loop(name):
+    """The level-synchronous numpy oracle equals the queue BFS exactly,
+    unreached vertices included, on undirected and directed graphs."""
+    if name.startswith("directed"):
+        src, dst = rmat_edges(10, 4, seed=3)
+        csr = csr_from_edges(src, dst, 1 << 10)
+    else:
+        csr = get_dataset(name).csr
+    deg = np.diff(csr.indptr)
+    roots = [0, int(np.argmax(deg)), int(np.flatnonzero(deg == 0)[0])
+             if (deg == 0).any() else 1]
+    for root in roots:
+        want = _bfs_loop(csr, root)
+        got = bfs_oracle(csr, root)
+        unreached = want == np.iinfo(np.int32).max
+        np.testing.assert_array_equal(got[~unreached], want[~unreached])
+        assert (got[unreached] == int(np.asarray(2 ** 30))).all()
+        assert got.dtype == np.int64

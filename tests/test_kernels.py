@@ -158,19 +158,6 @@ def test_msbfs_propagate_rejects_unknown_op():
         ref.msbfs_propagate_planes_ref(frontier, seen, src, tgt, op="xor")
 
 
-def test_msbfs_propagate_parity_noninterpret():
-    """Non-interpret arm of the parity harness (TPU-only compile)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("non-interpret Pallas path needs a TPU backend")
-    from repro.kernels.msbfs_propagate import msbfs_propagate_planes
-    frontier, seen, src, tgt = _propagate_case(65, 1, 128, seed=0)
-    got = msbfs_propagate_planes(frontier, seen, src, tgt,
-                                 block_edges=64, interpret=False)
-    want = ref.msbfs_propagate_planes_ref(frontier, seen, src, tgt)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-
-
 def test_msbfs_propagate_wrapper_masks_and_pads():
     """ops.msbfs_propagate: invalid / OOR edges drop, count is exact, and
     the scatter-OR matches a per-edge numpy loop (independent oracle)."""
@@ -252,8 +239,9 @@ def test_segment_or_rows_matches_loop():
     first = np.zeros(e_, bool)
     first[np.sort(rng.choice(e_, 25, replace=False))] = True
     first[0] = True
+    seg = np.cumsum(first).astype(np.int32)       # segment id per row
     got = np.asarray(bitmap.segment_or_rows(jnp.asarray(msg),
-                                            jnp.asarray(first)))
+                                            jnp.asarray(seg)))
     want = np.zeros_like(msg)
     cur = np.zeros(nw, np.uint32)
     for e in range(e_):

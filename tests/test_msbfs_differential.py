@@ -119,23 +119,6 @@ def test_one_host_transfer_per_level():
     assert r1.host_transfers == r1.iterations + 2
 
 
-def test_propagate_noninterpret_call_path():
-    """Exercise the non-interpret kernel call path (compiles only on TPU)."""
-    import jax
-    from repro.kernels import ops as kops
-    import jax.numpy as jnp
-    if jax.default_backend() != "tpu":
-        pytest.skip("non-interpret Pallas path needs a TPU backend")
-    fw = jnp.asarray(np.random.default_rng(0).integers(
-        0, 2**32, (64, 1), dtype=np.uint32))
-    sw = jnp.zeros((64, 1), jnp.uint32)
-    src = jnp.arange(64, dtype=jnp.int32)
-    new, seen, cnt = kops.msbfs_propagate(fw, sw, src, src,
-                                          jnp.ones(64, bool),
-                                          interpret=False)
-    assert new.shape == (64, 1)
-
-
 def test_isolated_root_reaches_only_itself():
     csr, g = _awkward_graph(N, 512, seed=0)
     res = MultiSourceBFSRunner(g).run(np.asarray([N - 1], np.int32))

@@ -9,7 +9,6 @@ edges span / concentrate on tiles, batch widths around the word boundary
 that select it.
 """
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -178,32 +177,13 @@ def test_sequential_loop_body_matches_vectorized(op):
     sm, st, ct = ops._bucket_edges_by_tile(
         msg, tg, jnp.ones(half, bool), n // TILE, TILE, BLOCK)
     loop, vec = (msbfs_propagate_planes_tiled(
-        jnp.asarray(sn), sm, st, ct, tile_rows=TILE, block_edges=BLOCK,
+        jnp.asarray(sn), sm.reshape(-1), st, ct, tile_rows=TILE,
+        block_edges=BLOCK,
         interpret=True, op=op, vector_scatter=v)
         for v in (False, True))
     for a, b, name in zip(loop, vec, ("new", "seen", "cnt")):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f"tiled kernel: {name}")
-
-
-def test_tiled_noninterpret_parity():
-    """Non-interpret arm of the tiled differential (TPU-only compile)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("non-interpret Pallas path needs a TPU backend")
-    n, nw = 8 * TILE, 1
-    frontier, seen = _planes(n, nw, seed=31)
-    rng = np.random.default_rng(32)
-    m = 500
-    src = rng.integers(0, n, m).astype(np.int32)
-    tgt = rng.integers(0, n, m).astype(np.int32)
-    args = (jnp.asarray(frontier), jnp.asarray(seen), jnp.asarray(src),
-            jnp.asarray(tgt), jnp.ones(m, bool))
-    got = ops.msbfs_propagate(*args, block_edges=128, interpret=False,
-                              tile_rows=TILE)
-    want = ops.msbfs_propagate(*args, block_edges=128, interpret=True,
-                               tile_rows=TILE)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------
@@ -238,11 +218,17 @@ def test_bucket_edges_by_tile_invariants():
 
 
 def test_propagate_plan_selection():
-    # rmat16 @ B=32 stays whole-VMEM under the default ~2 MiB budget;
-    # rmat20 and wide batches tile
-    assert not ops.propagate_plan(1 << 16, 1)["tiled"]
+    # plane rows pad to 128 lanes in VMEM, so under the default 16 MiB
+    # budget only graphs of a few thousand vertices stay whole-VMEM, at
+    # any batch up to 128 planes words; rmat16 and larger tile
+    assert not ops.propagate_plan(4000, 1)["tiled"]
+    assert not ops.propagate_plan(4000, 4)["tiled"]
+    assert ops.propagate_plan(1 << 16, 1)["tiled"]
     assert ops.propagate_plan(1 << 20, 1)["tiled"]
     assert ops.propagate_plan(1 << 16, 4)["tiled"]
+    # the tile rule counts the padded row: 16 MiB / (8 x 512 B) rows
+    assert ops.propagate_plan(1 << 20, 1)["tile_rows"] == 4096
+    assert ops._auto_block_edges(1 << 30, 1) == ops.MAX_BLOCK_EDGES
     # explicit budget override + forced modes
     p = ops.propagate_plan(1000, 1, vmem_bytes=1024)
     assert p["tiled"] and p["tile_rows"] >= 8
