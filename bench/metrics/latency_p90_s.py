@@ -1,0 +1,9 @@
+"""90th percentile of the latency of the window's requests, from when each was due."""
+import numpy as np
+
+from readings import latencies
+
+
+def read(run):
+    lat = latencies(run)
+    return float(np.percentile(lat, 90)) if lat.size else None

@@ -1,0 +1,7 @@
+"""Device milliseconds per wave in the pull step program."""
+from trace import program_seconds
+
+
+def read(run):
+    s = program_seconds(run.trace, "vp_pull_step") if run.trace else None
+    return 1000.0 * s / len(run.waves) if s and run.waves else None
