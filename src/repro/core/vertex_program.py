@@ -49,6 +49,9 @@ from repro.core.bfs_local import (INF, SV_COUNT, SV_MF, SV_MU, SV_NF,
                                   expand_edges, validate_roots)
 from repro.core.scheduler import (PUSH, SchedulerConfig, choose_mode,
                                   choose_mode_host)
+from repro.spans import span
+
+MODE_NAMES = ("push", "pull")    # by scheduler mode (PUSH, PULL)
 
 
 # ---------------------------------------------------------------------------
@@ -606,6 +609,11 @@ class VertexProgramRunner:
         self._transfers += 1
         return np.asarray(arr)
 
+    def _sync(self, statvec, level: int, retry: int) -> np.ndarray:
+        """The blocking statvec fetch after the init (level 0) or a step."""
+        with span("vp.sync", level=level, retry=retry):
+            return self._fetch(statvec)
+
     def _fetch_pair(self, a, b):
         """One blocking device->host round trip for two device values."""
         self._transfers += 1
@@ -698,76 +706,99 @@ class VertexProgramRunner:
     def _run_packed(self, roots: np.ndarray,
                     budget_override: int | None = None
                     ) -> VertexProgramResult:
+        g = self.g
+        # no point budgeting past the whole edge array (keeps the budgeted
+        # kernels small on tiny graphs); the overflow loop still deepens
+        budget = min(budget_override or self.init_budget,
+                     max(g.out_indices.shape[0], g.in_indices.shape[0]) + 1)
+        with span("vp.wave", slots=int(roots.size), budget=budget):
+            return self._traverse(roots, budget)
+
+    def _traverse(self, roots: np.ndarray,
+                  budget: int) -> VertexProgramResult:
         g, program = self.g, self.program
         b = int(roots.size)
         check = self.integrity != "off"
         witness = self.integrity in ("witness", "audit")
         corrupt, self._corrupt_plane = self._corrupt_plane, None
         pcs: list[int] = []         # per-level discovery popcounts
-        t0 = time.perf_counter()
-        frontier, seen, value, statvec = vp_init_state(
-            g, jnp.asarray(roots), program, check=check)
-        sv = self._fetch(statvec)
-        if check:
-            self._guard_sv(sv, 0, b, 0)
-        pcs.append(int(sv[SV_COUNT]))
+        levels: list[dict] = []     # per-level counters (last_stats)
         mode = PUSH
         lvl = 0
         inspected = 0
         push_iters = pull_iters = 0
         overflow_retries = 0
-        # no point budgeting past the whole edge array (keeps the budgeted
-        # kernels small on tiny graphs); the overflow loop still deepens
-        budget = min(budget_override or self.init_budget,
-                     max(g.out_indices.shape[0], g.in_indices.shape[0]) + 1)
+        t0 = time.perf_counter()
+        with span("vp.init"):
+            frontier, seen, value, statvec = vp_init_state(
+                g, jnp.asarray(roots), program, check=check)
+        sv = self._sync(statvec, 0, 0)
+        if check:
+            self._guard_sv(sv, 0, b, 0)
+        pcs.append(int(sv[SV_COUNT]))
         while not program.done(sv):
-            mode = choose_mode_host(self.sched, mode, int(sv[SV_NF]),
-                                    int(sv[SV_MF]), int(sv[SV_MU]), g.n,
-                                    int(sv[SV_NU]))
-            # the scan-based pull is dense over the CSC edge stream: only
-            # push (and the budgeted Pallas/sparse pulls) need a budget
-            budgeted = mode == PUSH or self.use_pallas
-            step_budget = 0
-            if budgeted:
+            # vp.level.host: the host's work from the statvec to the return
+            # of the next step's dispatch
+            with span("vp.level.host", level=lvl, retry=0) as host:
+                mode = choose_mode_host(self.sched, mode, int(sv[SV_NF]),
+                                        int(sv[SV_MF]), int(sv[SV_MU]), g.n,
+                                        int(sv[SV_NU]))
                 need = int(sv[SV_MF]) if mode == PUSH else int(sv[SV_MU])
-                cap = (g.out_indices if mode == PUSH
-                       else g.in_indices).shape[0]
-                while budget < min(need, cap + 1):
-                    budget *= 2
-                step_budget = budget
-            elif self.sparse_pull:
-                # per-level choice (NOT the ratcheting push budget): tail
-                # levels shrink, so the pull budget must shrink with them
-                step_budget = self._pull_budget(int(sv[SV_MU]))
-            step = vp_push_step if mode == PUSH else vp_pull_step
-            if corrupt is not None and lvl == int(corrupt[0]):
-                # chaos hook: flip one frontier plane bit, exact-once
-                frontier = _xor_plane_bit(frontier, corrupt[1], corrupt[2])
-                corrupt = None
-            # retry from the PRE-step seen: an overflowed (truncated) step
-            # may have committed a partial discovery set
-            state0 = (frontier, seen, value)
-            frontier, seen, value, statvec = step(
-                g, *state0, np.int32(lvl), program, step_budget,
-                self.use_pallas, self.tile_rows, check=check)
-            sv = self._fetch(statvec)
-            if check:
-                self._guard_sv(sv, lvl, b, sum(pcs))
-            while step_budget and bool(sv[SV_OVERFLOW]):
-                overflow_retries += 1   # surfaced in last_stats / result
-                if (self.max_overflow_retries is not None
-                        and overflow_retries > self.max_overflow_retries):
-                    raise BudgetOverflowError(step_budget, int(sv[SV_MF]),
-                                              overflow_retries)
-                step_budget *= 2       # HBM-reader queue overflow: deepen
+                # the scan-based pull is dense over the CSC edge stream:
+                # only push (and the budgeted Pallas/sparse pulls) need a
+                # budget
+                budgeted = mode == PUSH or self.use_pallas
+                step_budget = 0
                 if budgeted:
-                    budget = step_budget
+                    cap = (g.out_indices if mode == PUSH
+                           else g.in_indices).shape[0]
+                    while budget < min(need, cap + 1):
+                        budget *= 2
+                    step_budget = budget
+                elif self.sparse_pull:
+                    # per-level choice (NOT the ratcheting push budget):
+                    # tail levels shrink, so the pull budget must shrink
+                    # with them
+                    step_budget = self._pull_budget(int(sv[SV_MU]))
+                step = vp_push_step if mode == PUSH else vp_pull_step
+                if corrupt is not None and lvl == int(corrupt[0]):
+                    # chaos hook: flip one frontier plane bit, exact-once
+                    frontier = _xor_plane_bit(frontier, corrupt[1],
+                                              corrupt[2])
+                    corrupt = None
+                # retry from the PRE-step seen: an overflowed (truncated)
+                # step may have committed a partial discovery set
+                state0 = (frontier, seen, value)
+                host.set_metadata(mode=MODE_NAMES[mode], budget=step_budget)
                 frontier, seen, value, statvec = step(
                     g, *state0, np.int32(lvl), program, step_budget,
                     self.use_pallas, self.tile_rows, check=check)
-                sv = self._fetch(statvec)
-                if check:
-                    self._guard_sv(sv, lvl, b, sum(pcs))
+            sv = self._sync(statvec, lvl, 0)
+            retries = 0
+            while step_budget and bool(sv[SV_OVERFLOW]):
+                retries += 1
+                with span("vp.level.host", level=lvl, retry=retries,
+                          mode=MODE_NAMES[mode]) as host:
+                    if check:
+                        self._guard_sv(sv, lvl, b, sum(pcs))
+                    overflow_retries += 1   # surfaced in last_stats
+                    if (self.max_overflow_retries is not None
+                            and overflow_retries > self.max_overflow_retries):
+                        raise BudgetOverflowError(step_budget, int(sv[SV_MF]),
+                                                  overflow_retries)
+                    step_budget *= 2   # HBM-reader queue overflow: deepen
+                    if budgeted:
+                        budget = step_budget
+                    host.set_metadata(budget=step_budget)
+                    frontier, seen, value, statvec = step(
+                        g, *state0, np.int32(lvl), program, step_budget,
+                        self.use_pallas, self.tile_rows, check=check)
+                sv = self._sync(statvec, lvl, retries)
+            if check:
+                self._guard_sv(sv, lvl, b, sum(pcs))
+            levels.append(dict(mode=MODE_NAMES[mode], budget=step_budget,
+                               need=need, total=int(sv[SV_TOTAL]),
+                               retries=retries))
             pcs.append(int(sv[SV_COUNT]))
             lvl += 1
             inspected += int(sv[SV_TOTAL])
@@ -775,37 +806,39 @@ class VertexProgramRunner:
                 push_iters += 1
             else:
                 pull_iters += 1
-        value.block_until_ready()
-        dt = time.perf_counter() - t0
-        # per-plane traversed-edge counts, computed ON DEVICE and fetched
-        # with the value rows in ONE blocking transfer (host_transfers
-        # stays iterations + 2).  Each plane's count is <= E so int32 is
-        # safe; the cross-plane sum happens on host in int64.  The numpy
-        # recount this replaces cost tens of ms per wide wave.  With the
-        # witness audit on, its int32[2] verdict rides the SAME fetch.
-        wit = None
-        if witness:
-            k = min(self.witness_k, g.n)
-            sample = jnp.asarray(
-                self._witness_rng.integers(0, g.n, size=k), jnp.int32)
-            rows_cm, trav_np, wit = self._fetch_many(
-                value[: g.n], _plane_traversed(g, value),
-                _witness_check(g, value, sample, self.witness_budget))
-        else:
-            rows_cm, trav_np = self._fetch_pair(value[: g.n],
-                                                _plane_traversed(g, value))
-        rows = rows_cm.T                             # [B, n]
-        if check:
-            self._guard_rows(rows, roots, lvl)
-            if wit is not None and not int(wit[1]) and int(wit[0]):
-                raise IntegrityError(
-                    f"witness audit failed: {int(wit[0])} sampled "
-                    "(vertex, plane) discoveries have no in-neighbor at "
-                    "value - 1")
+        with span("vp.rows", slots=b):
+            value.block_until_ready()
+            dt = time.perf_counter() - t0
+            # per-plane traversed-edge counts, computed ON DEVICE and fetched
+            # with the value rows in ONE blocking transfer (host_transfers
+            # stays iterations + 2).  Each plane's count is <= E so int32 is
+            # safe; the cross-plane sum happens on host in int64.  The numpy
+            # recount this replaces cost tens of ms per wide wave.  With the
+            # witness audit on, its int32[2] verdict rides the SAME fetch.
+            wit = None
+            if witness:
+                k = min(self.witness_k, g.n)
+                sample = jnp.asarray(
+                    self._witness_rng.integers(0, g.n, size=k), jnp.int32)
+                rows_cm, trav_np, wit = self._fetch_many(
+                    value[: g.n], _plane_traversed(g, value),
+                    _witness_check(g, value, sample, self.witness_budget))
+            else:
+                rows_cm, trav_np = self._fetch_pair(value[: g.n],
+                                                    _plane_traversed(g, value))
+            rows = rows_cm.T                             # [B, n]
+            if check:
+                self._guard_rows(rows, roots, lvl)
+                if wit is not None and not int(wit[1]) and int(wit[0]):
+                    raise IntegrityError(
+                        f"witness audit failed: {int(wit[0])} sampled "
+                        "(vertex, plane) discoveries have no in-neighbor at "
+                        "value - 1")
         res = self._result(rows, b, lvl, inspected, push_iters,
                            pull_iters, dt, overflow_retries, budget,
                            trav_vec=trav_np)
         self.last_stats["discovery_popcounts"] = pcs
+        self.last_stats["levels"] = levels
         if check:
             self.last_stats["integrity"] = dict(
                 mode=self.integrity,

@@ -63,6 +63,7 @@ For a pool of engines behind one submit surface see ``launch.pool``.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -74,6 +75,7 @@ from repro.core import (bitmap, count_traversed_edges, engine_num_vertices,
                         validate_roots)
 from repro.ft.supervisor import (DETERMINISTIC, EngineSupervisor,
                                  classify_fault)
+from repro.spans import gc_spans, span
 
 
 class QueueFull(RuntimeError):
@@ -120,6 +122,7 @@ class WaveStats:
     timeouts: int = 0
     quarantined: list[int] = dataclasses.field(default_factory=list)
     demotions: list[str] = dataclasses.field(default_factory=list)
+    seq: int = -1               # ``wave`` arg of this wave's dynbatch spans
 
     @property
     def aggregate_teps(self) -> float | None:
@@ -142,6 +145,7 @@ class BFSFuture:
         self.latency: float | None = None   # injected-clock submit->resolve
         self.slo_miss: bool | None = None   # None: no deadline was set
         self._seq = 0                       # submit order (stable sort key)
+        self.req = -1                       # submit order: its span's req
         self._event = threading.Event()
         self._levels = None
         self._exc: BaseException | None = None
@@ -312,6 +316,8 @@ class DynamicBatcher:
         self._traversed = 0
         self._inflight = 0                # cut but not yet finished
         self._seq = 0
+        self._wave_ids = itertools.count()  # ``wave`` of the wave spans
+        gc_spans()
         self._pending: deque[BFSFuture] = deque()
         self._n_slo_pending = 0           # pending with deadline/priority
         self._cond = threading.Condition()
@@ -373,7 +379,7 @@ class DynamicBatcher:
             validate_roots(np.asarray([root]), self.num_vertices)
         if deadline is not None and deadline <= 0:
             raise ValueError(f"deadline must be > 0 seconds, got {deadline}")
-        with self._cond:
+        with span("dynbatch.submit") as submit_span, self._cond:
             if self._closed:
                 raise BatcherClosed("submit() on a closed DynamicBatcher")
             if (self.shed and deadline is not None
@@ -410,7 +416,8 @@ class DynamicBatcher:
             fut = BFSFuture(root, t_sub,
                             None if deadline is None else t_sub + deadline,
                             priority)
-            fut._seq = self._seq
+            fut._seq = fut.req = self._seq
+            submit_span.set_metadata(req=fut.req)
             self._seq += 1
             self._pending.append(fut)
             if fut.t_deadline is not None or fut.priority != 0:
@@ -608,24 +615,38 @@ class DynamicBatcher:
         full wave or an SLO preemption; cut; dispatch (or hand to the
         pipeline); repeat.  Drains the queue on close."""
         while True:
-            with self._cond:
-                while not self._pending and not self._closed:
-                    self._cond.wait()
-                if not self._pending:        # closed and drained
+            # dynbatch.cut: from the end of the previous wave's dispatch to
+            # the cut, so the wait for requests and the window
+            with span("dynbatch.cut") as cut_span:
+                cut = self._wait_for_cut()
+                if cut is None:              # closed and drained
                     return
-                cut = self._try_cut_locked(force=self._closed)
-                if cut is None:
-                    self._cond.wait(
-                        max(self._deadline_locked() - self.clock(), 0.0))
-                    continue
                 wave, preempted = cut
+                seq = next(self._wave_ids)
+                cut_span.set_metadata(wave=seq, batch=len(wave),
+                                      preempted=preempted)
             if self.pipeline:
                 # prepare on THIS thread (cutter stage), then hand off;
                 # put() blocks when pipeline_depth waves are already
                 # prepped — natural backpressure on the cutter
-                self._dispatch_q.put(self._prepare(wave, preempted))
+                self._dispatch_q.put(self._prepare(wave, preempted, seq))
             else:
-                self._dispatch(wave, preempted)
+                self._dispatch(wave, preempted, seq)
+
+    def _wait_for_cut(self) -> tuple[list[BFSFuture], bool] | None:
+        """Block until a wave is due and cut it: (futures, preempted), or
+        None once the batcher is closed and drained."""
+        with self._cond:
+            while True:
+                while not self._pending and not self._closed:
+                    self._cond.wait()
+                if not self._pending:
+                    return None
+                cut = self._try_cut_locked(force=self._closed)
+                if cut is not None:
+                    return cut
+                self._cond.wait(
+                    max(self._deadline_locked() - self.clock(), 0.0))
 
     def _pipeline_dispatcher(self):
         """Dispatcher stage: the ONLY thread that touches the engine."""
@@ -645,33 +666,37 @@ class DynamicBatcher:
 
     # -- dispatch stages --------------------------------------------------
 
-    def _dispatch(self, futures: list[BFSFuture],
-                  preempted: bool = False) -> WaveStats:
+    def _dispatch(self, futures: list[BFSFuture], preempted: bool = False,
+                  seq: int | None = None) -> WaveStats:
         """Synchronous dispatch: the three stages back-to-back (manual
         pump/flush mode and the non-pipelined worker)."""
-        execs = self._execute(self._prepare(futures, preempted))
+        execs = self._execute(self._prepare(futures, preempted, seq))
         return self._finish(execs)
 
-    def _prepare(self, futures: list[BFSFuture],
-                 preempted: bool = False) -> _Prepared:
-        """Cutter stage: validate + pad the wave, before the engine."""
-        roots = np.asarray([f.root for f in futures], np.int64)
-        b = len(futures)
-        if self.supervisor is not None:
-            # the supervisor pads internally (it may bisect the wave)
-            slots = roots
-            n_slots = (bitmap.num_words(b) * bitmap.WORD_BITS
-                       if self.supervisor.pad_to_plane else b)
-        else:
-            slots = roots
-            if self.pad_to_plane:
-                slots, b = bitmap.pad_plane_slots(roots)
-            n_slots = int(slots.size)
-        ws = WaveStats(wave_id=-1, batch=b, n_slots=n_slots,
-                       t_start=self.clock(), seconds=0.0, iterations=0,
-                       edges_inspected=0, push_iters=0, pull_iters=0,
-                       traversed_edges=None, preempted=preempted)
-        return _Prepared(futures=futures, slots=slots, b=b, ws=ws)
+    def _prepare(self, futures: list[BFSFuture], preempted: bool = False,
+                 seq: int | None = None) -> _Prepared:
+        """Cutter stage: validate + pad the wave, before the engine.
+        ``seq`` is the wave's span id (a fresh one when None)."""
+        if seq is None:
+            seq = next(self._wave_ids)
+        with span("dynbatch.prepare", wave=seq):
+            roots = np.asarray([f.root for f in futures], np.int64)
+            b = len(futures)
+            if self.supervisor is not None:
+                # the supervisor pads internally (it may bisect the wave)
+                slots = roots
+                n_slots = (bitmap.num_words(b) * bitmap.WORD_BITS
+                           if self.supervisor.pad_to_plane else b)
+            else:
+                slots = roots
+                if self.pad_to_plane:
+                    slots, b = bitmap.pad_plane_slots(roots)
+                n_slots = int(slots.size)
+            ws = WaveStats(wave_id=-1, batch=b, n_slots=n_slots,
+                           t_start=self.clock(), seconds=0.0, iterations=0,
+                           edges_inspected=0, push_iters=0, pull_iters=0,
+                           traversed_edges=None, preempted=preempted, seq=seq)
+            return _Prepared(futures=futures, slots=slots, b=b, ws=ws)
 
     def _wave_deadline(self, futures: list[BFSFuture]) -> float | None:
         """Tightest remaining request deadline, for the wave watchdog."""
@@ -687,61 +712,65 @@ class DynamicBatcher:
         wave's engine return and this wave's engine entry is time the
         engine spent waiting on the host.
         """
-        t0 = time.perf_counter()
-        with self._cond:
-            if self._last_exec_end is not None:
-                self._idle_seconds += max(t0 - self._last_exec_end, 0.0)
-        ws = prep.ws
-        try:
-            if self.supervisor is not None:
-                wave = self.supervisor.run_wave(
-                    prep.slots, deadline=self._wave_deadline(prep.futures))
-                out = [_Executed(prep=prep, wave=wave)]
-            else:
-                # BFSEngine protocol: run_batch + last_stats, no sniffing
-                levels = np.asarray(self.engine.run_batch(prep.slots))
-                ws.seconds = time.perf_counter() - t0
-                st = dict(getattr(self.engine, "last_stats", {}))
-                ws.iterations = int(st.get("iterations", 0))
-                ws.edges_inspected = int(st.get("edges_inspected", 0))
-                ws.push_iters = int(st.get("push_iters", 0))
-                ws.pull_iters = int(st.get("pull_iters", 0))
-                tpp = st.get("traversed_per_plane")
-                if tpp is not None:
-                    # pad slots sliced off here, no host recount needed
-                    ws.traversed_edges = int(
-                        np.sum(np.asarray(tpp[: prep.b], np.int64)))
-                out = [_Executed(prep=prep, levels=levels)]
-        except Exception as exc:       # resolve, don't kill the worker
-            ws.seconds = time.perf_counter() - t0
-            out = [_Executed(prep=prep, exc=exc)]
-            if (self.supervisor is None
-                    and classify_fault(exc) == DETERMINISTIC
-                    and len(prep.futures) > 1):
-                # a root rejected at dispatch time (possible when submit
-                # had no |V| to validate against) must not fail its
-                # co-batched neighbors: isolate each request as its own
-                # singleton wave.  CAPPED: the len > 1 guard means a
-                # failing singleton fails its future outright — no
-                # request is ever retried more than once, and transient
-                # faults never take this path (they fail the wave's
-                # futures below; wrap the engine in an EngineSupervisor
-                # for retry/backoff/bisection policy instead).  The
-                # singleton re-runs happen HERE, on the dispatcher
-                # thread — they are engine calls.
-                out[0].futures_owned_elsewhere = True
-                for f in prep.futures:
-                    out.extend(self._execute(self._prepare([f])))
-        finally:
+        # dynbatch.execute opens and closes at the marks of the engine-idle
+        # accounting, so the two agree
+        with span("dynbatch.execute", wave=prep.ws.seq):
+            t0 = time.perf_counter()
             with self._cond:
-                self._last_exec_end = time.perf_counter()
+                if self._last_exec_end is not None:
+                    self._idle_seconds += max(t0 - self._last_exec_end, 0.0)
+            ws = prep.ws
+            try:
+                if self.supervisor is not None:
+                    wave = self.supervisor.run_wave(
+                        prep.slots, deadline=self._wave_deadline(prep.futures))
+                    out = [_Executed(prep=prep, wave=wave)]
+                else:
+                    # BFSEngine protocol: run_batch + last_stats, no sniffing
+                    levels = np.asarray(self.engine.run_batch(prep.slots))
+                    ws.seconds = time.perf_counter() - t0
+                    st = dict(getattr(self.engine, "last_stats", {}))
+                    ws.iterations = int(st.get("iterations", 0))
+                    ws.edges_inspected = int(st.get("edges_inspected", 0))
+                    ws.push_iters = int(st.get("push_iters", 0))
+                    ws.pull_iters = int(st.get("pull_iters", 0))
+                    tpp = st.get("traversed_per_plane")
+                    if tpp is not None:
+                        # pad slots sliced off here, no host recount needed
+                        ws.traversed_edges = int(
+                            np.sum(np.asarray(tpp[: prep.b], np.int64)))
+                    out = [_Executed(prep=prep, levels=levels)]
+            except Exception as exc:       # resolve, don't kill the worker
+                ws.seconds = time.perf_counter() - t0
+                out = [_Executed(prep=prep, exc=exc)]
+                if (self.supervisor is None
+                        and classify_fault(exc) == DETERMINISTIC
+                        and len(prep.futures) > 1):
+                    # a root rejected at dispatch time (possible when submit
+                    # had no |V| to validate against) must not fail its
+                    # co-batched neighbors: isolate each request as its own
+                    # singleton wave.  CAPPED: the len > 1 guard means a
+                    # failing singleton fails its future outright — no
+                    # request is ever retried more than once, and transient
+                    # faults never take this path (they fail the wave's
+                    # futures below; wrap the engine in an EngineSupervisor
+                    # for retry/backoff/bisection policy instead).  The
+                    # singleton re-runs happen HERE, on the dispatcher
+                    # thread — they are engine calls.
+                    out[0].futures_owned_elsewhere = True
+                    for f in prep.futures:
+                        out.extend(self._execute(self._prepare([f])))
+            finally:
+                with self._cond:
+                    self._last_exec_end = time.perf_counter()
         return out
 
     def _finish(self, execs: list[_Executed]) -> WaveStats:
         """Finisher stage: slice rows, resolve futures, book stats."""
         first: WaveStats | None = None
         for ex in execs:
-            ws = self._finish_one(ex)
+            with span("dynbatch.finish", wave=ex.prep.ws.seq):
+                ws = self._finish_one(ex)
             if first is None:
                 first = ws
         return first
@@ -973,7 +1002,6 @@ class DynamicBatcher:
                 latency_mean=round(float(lats.mean()), 4),
                 latency_p50=round(float(np.percentile(lats, 50)), 4),
                 latency_p99=round(float(np.percentile(lats, 99)), 4),
-                latency_p999=round(float(np.percentile(lats, 99.9)), 4),
             )
         return out
 

@@ -464,7 +464,6 @@ class WorkerPool:
                 latency_mean=round(float(a.mean()), 4),
                 latency_p50=round(float(np.percentile(a, 50)), 4),
                 latency_p99=round(float(np.percentile(a, 99)), 4),
-                latency_p999=round(float(np.percentile(a, 99.9)), 4),
             )
         if any("fault_tolerance" in p for p in per):
             out["fault_tolerance"] = [p.get("fault_tolerance")

@@ -184,6 +184,7 @@ def bfs_batch(roots, *, graph: str = "rmat16-16", engine=None,
     if out_deg is not None:
         traversed = count_traversed_edges(out_deg, levels)
     stats.pop("seconds", None)
+    stats.pop("levels", None)       # per-level counters; the rows take the key
     stats["batch"] = int(roots.size)
     out = dict(levels=levels, seconds=round(seconds, 4), **stats)
     if traversed is not None:

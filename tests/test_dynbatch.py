@@ -745,7 +745,7 @@ def test_pipelined_serving_matches_oracle(graph, engine):
     assert s["pipeline"] is True
     assert s["requests"] == len(roots)
     assert s["engine_idle_seconds"] >= 0.0
-    assert s["latency_p999"] >= s["latency_p99"] >= s["latency_p50"]
+    assert s["latency_p99"] >= s["latency_p50"]
 
 
 def test_pipelined_supervised_chaos_resolves_everything(graph, engine):
