@@ -494,6 +494,11 @@ def msbfs_reference(g: LocalGraph, roots, max_iters: int | None = None):
 # Results + the generic one-sync-per-level driver
 # ---------------------------------------------------------------------------
 
+def _next_pow2(x: int) -> int:
+    """Smallest power of two at or above ``x`` (1 for ``x <= 1``)."""
+    return 1 << max(0, int(x) - 1).bit_length()
+
+
 @dataclasses.dataclass
 class VertexProgramResult:
     levels: np.ndarray          # int32[B, n] — one value row per plane
@@ -512,7 +517,7 @@ class VertexProgramResult:
     algo: str = "bfs"
     labels: np.ndarray | None = None   # CC: int64[n] min-seed labels
     overflow_retries: int = 0   # levels re-run after a truncated push/pull
-    budget: int = 0             # final edge budget the run settled on
+    budget: int = 0             # largest edge budget a level of the run used
 
     @property
     def distances(self) -> np.ndarray:
@@ -677,7 +682,7 @@ class VertexProgramRunner:
         several times the static-boundary scan's, so it only engages well
         below the full CSC stream — full-ish levels stay dense."""
         cap = int(self.g.in_indices.shape[0])
-        pb = 1 << max(12, (max(m_u, 1) - 1).bit_length())
+        pb = max(1 << 12, _next_pow2(m_u))
         return pb if pb * 8 <= cap else 0
 
     def run(self, roots, *, budget: int | None = None) -> VertexProgramResult:
@@ -690,10 +695,12 @@ class VertexProgramRunner:
     def run_batch(self, roots, *, budget: int | None = None) -> np.ndarray:
         """Engine-protocol entry: value rows [B, n] + ``last_stats``.
 
-        ``budget`` overrides ``init_budget`` for THIS wave only — the
-        serving supervisor uses it to escalate the edge budget on a retry
-        after persistent push-budget overflow, without re-tuning the
-        engine's steady-state starting point.
+        ``budget`` overrides ``init_budget`` for THIS wave only: it is the
+        floor of every budgeted level's edge budget, each level running at
+        the larger of the floor and the next power of two at or above its
+        need.  The serving supervisor uses it to escalate the edge budget
+        on a retry after persistent push-budget overflow, without re-tuning
+        the engine's steady-state starting point.
         """
         return self.run(roots, budget=budget).levels
 
@@ -709,13 +716,13 @@ class VertexProgramRunner:
         g = self.g
         # no point budgeting past the whole edge array (keeps the budgeted
         # kernels small on tiny graphs); the overflow loop still deepens
-        budget = min(budget_override or self.init_budget,
-                     max(g.out_indices.shape[0], g.in_indices.shape[0]) + 1)
-        with span("vp.wave", slots=int(roots.size), budget=budget):
-            return self._traverse(roots, budget)
+        floor = min(budget_override or self.init_budget,
+                    max(g.out_indices.shape[0], g.in_indices.shape[0]) + 1)
+        with span("vp.wave", slots=int(roots.size), budget=floor):
+            return self._traverse(roots, floor)
 
     def _traverse(self, roots: np.ndarray,
-                  budget: int) -> VertexProgramResult:
+                  floor: int) -> VertexProgramResult:
         g, program = self.g, self.program
         b = int(roots.size)
         check = self.integrity != "off"
@@ -728,6 +735,7 @@ class VertexProgramRunner:
         inspected = 0
         push_iters = pull_iters = 0
         overflow_retries = 0
+        budget = floor              # largest budget any level used
         t0 = time.perf_counter()
         with span("vp.init"):
             frontier, seen, value, statvec = vp_init_state(
@@ -750,15 +758,14 @@ class VertexProgramRunner:
                 budgeted = mode == PUSH or self.use_pallas
                 step_budget = 0
                 if budgeted:
+                    # ``need`` is exactly what the step expands, so the
+                    # next power of two at or above it cannot overflow;
+                    # each level picks its own rung, so tail levels run
+                    # small after a wide one
                     cap = (g.out_indices if mode == PUSH
                            else g.in_indices).shape[0]
-                    while budget < min(need, cap + 1):
-                        budget *= 2
-                    step_budget = budget
+                    step_budget = max(floor, _next_pow2(min(need, cap + 1)))
                 elif self.sparse_pull:
-                    # per-level choice (NOT the ratcheting push budget):
-                    # tail levels shrink, so the pull budget must shrink
-                    # with them
                     step_budget = self._pull_budget(int(sv[SV_MU]))
                 step = vp_push_step if mode == PUSH else vp_pull_step
                 if corrupt is not None and lvl == int(corrupt[0]):
@@ -787,8 +794,6 @@ class VertexProgramRunner:
                         raise BudgetOverflowError(step_budget, int(sv[SV_MF]),
                                                   overflow_retries)
                     step_budget *= 2   # HBM-reader queue overflow: deepen
-                    if budgeted:
-                        budget = step_budget
                     host.set_metadata(budget=step_budget)
                     frontier, seen, value, statvec = step(
                         g, *state0, np.int32(lvl), program, step_budget,
@@ -796,6 +801,8 @@ class VertexProgramRunner:
                 sv = self._sync(statvec, lvl, retries)
             if check:
                 self._guard_sv(sv, lvl, b, sum(pcs))
+            if budgeted:
+                budget = max(budget, step_budget)
             levels.append(dict(mode=MODE_NAMES[mode], budget=step_budget,
                                need=need, total=int(sv[SV_TOTAL]),
                                retries=retries))

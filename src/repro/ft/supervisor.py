@@ -245,8 +245,8 @@ class EngineSupervisor:
         known to be slower, and without slack a demoted wave would trip
         the same watchdog that the demotion was meant to satisfy.
     escalate_budget: retry ``BudgetOverflowError`` waves with a doubled
-        edge budget, and start later waves at the deepest budget a
-        previous wave settled on (both via ``run_batch(budget=)``).
+        edge budget, and start later waves at the largest budget a
+        previous wave used (both via ``run_batch(budget=)``).
     pad_to_plane: pad every engine call to whole uint32 plane words so
         bisection sub-waves reuse the jitted wave shapes.
     integrity: an :class:`~repro.ft.integrity.IntegrityConfig` (or a mode
@@ -487,7 +487,7 @@ class EngineSupervisor:
                         and stats.get("overflow_retries", 0) > 0
                         and stats.get("budget", 0) > 0):
                     # the wave deepened mid-flight: start later waves at
-                    # the budget it settled on instead of re-deepening
+                    # the largest budget it used instead of re-deepening
                     self._budget_hint = int(stats["budget"])
                 for o, row in zip(outcomes, rows):
                     o.levels = np.ascontiguousarray(row)

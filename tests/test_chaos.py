@@ -50,6 +50,16 @@ def served():
     roots[B + B // 2] = poison          # one poisoned request, wave 2
     runner = MultiSourceBFSRunner(g)
     runner.run(np.resize(roots, B))     # warm the packed 32-slot shape
+    # and every push budget rung below |E|, as a serving deployment warms
+    # them: a flipped frontier bit can send a level to a rung the clean
+    # waves never use, and a compile inside a watched wave outlasts the
+    # 1 s deadline on a loaded host.  A wave over a vertex without arcs
+    # runs its one push level at exactly the given budget.
+    bare = np.full(B, np.flatnonzero(deg == 0)[0])
+    rung = runner.init_budget
+    while rung <= ds.csr.indices.size:
+        runner.run_batch(bare, budget=rung)
+        rung *= 2
     ref = {}
     for lo in range(0, REQUESTS, B):
         wave = np.resize(roots[lo:lo + B], B)

@@ -8,6 +8,15 @@ span the readers key on, with their args, read back through
 ``last_stats["levels"]`` holds one record per level that agrees with the
 wave's own counts; and the answers are the same with the profiler on and
 off.
+
+The records also pin the per-level edge budget: each budgeted level runs
+at ``max(floor, next_pow2(need))``, the floor being the wave's starting
+budget (``init_budget`` or the ``run_batch(budget=)`` override, capped at
+the edge count plus one).  On a small Kronecker graph a 32-root wave goes
+push (wide) -> pull -> push (narrow), so the tail push level runs below
+the wave's largest rung; a wave over roots with no arcs runs its one push
+level at exactly the override (the bench's warm-up relies on it); an
+overflowed level re-runs at twice its rung, and only that level does.
 """
 import gc
 
@@ -18,9 +27,11 @@ from jax.profiler import ProfileData
 
 import repro.core.vertex_program as vp
 from repro.core import (ConnectedComponentsRunner, MultiSourceBFSRunner,
-                        bfs_oracle, build_local_graph)
+                        SSSPRunner, bfs_oracle, build_local_graph,
+                        msbfs_reference)
 from repro.core.bfs_local import SV_OVERFLOW
-from repro.graph import csr_from_edges, rmat_edges, transpose_csr
+from repro.graph import (csr_from_edges, rmat_edges, symmetrize_csr,
+                         transpose_csr)
 from repro.launch.dynbatch import DynamicBatcher
 from repro.spans import gc_spans
 
@@ -215,6 +226,82 @@ def test_level_records_agree_with_the_wave(graph, monkeypatch, case):
     assert st["overflow_retries"] == (case == "bfs_overflow")
     modes = {r["mode"] for r in st["levels"]}
     assert "push" in modes
+
+
+FLOOR = 1 << 8
+RUNNERS = {"bfs": MultiSourceBFSRunner, "cc": ConnectedComponentsRunner,
+           "sssp": SSSPRunner}
+
+
+@pytest.fixture(scope="module")
+def kron():
+    """Scale-12 Kronecker graph (symmetrized, so CC runs on it too) and 32
+    roots of nonzero degree."""
+    n = 1 << 12
+    src, dst = rmat_edges(12, 16, seed=3)
+    csr = symmetrize_csr(csr_from_edges(src, dst, n))
+    deg = np.diff(csr.indptr)
+    roots = np.random.default_rng(0).choice(np.flatnonzero(deg > 0), 32,
+                                            replace=False)
+    return csr, build_local_graph(csr, csr), roots
+
+
+def _rung(rec, floor):
+    return max(floor, 1 << max(0, rec["need"] - 1).bit_length())
+
+
+def _push_levels(st):
+    return [r for r in st["levels"] if r["mode"] == "push"]
+
+
+@pytest.mark.parametrize("algo", sorted(RUNNERS))
+def test_each_push_level_picks_its_own_rung(kron, algo):
+    csr, g, roots = kron
+    eng = RUNNERS[algo](g, init_budget=FLOOR)
+    res = eng.run(roots)
+    st = eng.last_stats
+    _check_levels(st)
+    modes = [r["mode"] for r in st["levels"]]
+    # push (wide) -> pull ... -> push (narrow)
+    assert modes[0] == "push" and modes[-1] == "push" and "pull" in modes
+    push = _push_levels(st)
+    for r in push:
+        assert r["retries"] == 0 and r["budget"] == _rung(r, FLOOR), r
+    top = max(r["budget"] for r in push)
+    assert push[-1]["budget"] < top        # the tail runs below the peak
+    assert res.budget == st["budget"] == top
+    np.testing.assert_array_equal(res.levels, msbfs_reference(g, roots))
+
+
+@pytest.mark.parametrize("rung", [1 << 8, 1 << 12, 1 << 15, 1 << 20])
+def test_override_is_the_floor_of_an_empty_push_level(kron, rung):
+    """Roots without arcs: one push level with need 0, at exactly the
+    override (capped at the edge count plus one)."""
+    csr, g, _ = kron
+    isolated = np.flatnonzero(np.diff(csr.indptr) == 0)
+    assert isolated.size
+    eng = MultiSourceBFSRunner(g)
+    eng.run_batch(np.full(32, isolated[0]), budget=rung)
+    floor = min(rung, int(csr.indices.size) + 1)
+    assert eng.last_stats["levels"] == [dict(mode="push", budget=floor,
+                                             need=0, total=0, retries=0)]
+    assert eng.last_stats["budget"] == floor
+
+
+def test_overflow_doubles_only_its_own_level(kron, monkeypatch):
+    """The first push level re-runs at twice its rung; the later levels
+    pick their rungs from their own need."""
+    csr, g, roots = kron
+    overflow_once(monkeypatch)
+    eng = MultiSourceBFSRunner(g, init_budget=FLOOR)
+    rows = eng.run_batch(roots)
+    push = _push_levels(eng.last_stats)
+    assert push[0]["retries"] == 1
+    assert push[0]["budget"] == 2 * _rung(push[0], FLOOR)
+    for r in push[1:]:
+        assert r["retries"] == 0 and r["budget"] == _rung(r, FLOOR)
+    assert eng.last_stats["budget"] == push[0]["budget"]
+    np.testing.assert_array_equal(rows, msbfs_reference(g, roots))
 
 
 @pytest.mark.parametrize("wave", ["stats", "plain"])
