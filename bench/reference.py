@@ -1,4 +1,4 @@
-"""Plain reference BFS, and the control that must fail the comparison.
+"""Plain reference BFS levels and parent trees, and their controls.
 
 Independent of the program: it imports nothing from ``repro`` and reads
 only the benchmark's own CSR.  Up to 64 roots run together, one bit per
@@ -13,6 +13,12 @@ might be tempted by: each level expands only the first ``budget`` arcs of
 the frontier's out-lists and drops the rest instead of re-running the level
 with a larger budget.  It breaks the guarantee the configurations state
 (exact hop levels) and must come out as not correct.
+
+``bfs_parents`` gives each reached vertex its smallest-id neighbour on the
+previous level.  ``sibling_parents`` is its control: the largest-id
+neighbour whose level is at most the vertex's own, the shortcut of a push
+that writes parents after the level's commit, so that a vertex can adopt a
+sibling found in the same level.
 """
 from __future__ import annotations
 
@@ -101,3 +107,48 @@ def mismatches(got_rows, want: np.ndarray) -> int:
     """Number of (request, vertex) levels that differ from the reference."""
     return int(sum(int(np.count_nonzero(np.asarray(g, np.int64) != w))
                    for g, w in zip(got_rows, want)))
+
+
+def parents_from_levels(indptr: np.ndarray, indices: np.ndarray, roots,
+                        levels: np.ndarray, *, same_level: bool = False
+                        ) -> np.ndarray:
+    """int32[len(roots), n] parent rows over given level rows: ``root`` at
+    each root, -1 where unreached, else the smallest-id neighbour one level
+    up or, with ``same_level``, the largest-id neighbour at most as deep."""
+    n = len(indptr) - 1
+    deg = np.diff(indptr)
+    nonempty = np.flatnonzero(deg > 0)
+    starts = indptr[nonempty]
+    out = np.full((len(roots), n), -1, np.int32)
+    for row, r, lvl in zip(out, np.asarray(roots, np.int64), levels):
+        lvl = np.asarray(lvl, np.int32)
+        mine = np.repeat(lvl, deg)
+        theirs = lvl[indices]
+        if same_level:
+            pick = np.maximum.reduceat(
+                np.where(theirs <= mine, indices, -1), starts)
+        else:
+            pick = np.minimum.reduceat(
+                np.where(theirs == mine - 1, indices, n), starts)
+        row[nonempty] = np.where(pick < n, pick, -1)
+        row[lvl >= INF] = -1
+        row[r] = r
+    return out
+
+
+def bfs_parents(indptr: np.ndarray, indices: np.ndarray, roots) -> np.ndarray:
+    """int32[len(roots), n] BFS parent rows (``parent[root] == root``, -1
+    where unreached): each reached vertex's smallest-id neighbour on the
+    previous level, on a symmetric CSR."""
+    return parents_from_levels(indptr, indices, roots,
+                               bfs_levels(indptr, indices, roots))
+
+
+def sibling_parents(indptr: np.ndarray, indices: np.ndarray,
+                    roots) -> np.ndarray:
+    """The control of the parent check: each reached vertex's largest-id
+    neighbour whose level is at most its own, so a sibling of the same
+    level where one has the larger id."""
+    return parents_from_levels(indptr, indices, roots,
+                               bfs_levels(indptr, indices, roots),
+                               same_level=True)

@@ -4,9 +4,10 @@ Every sampler draws from the vertices of nonzero degree, as GAP's BFS
 benchmark and Graph500 pick their search keys.  The sequence of roots comes
 from the mix's ``pool_seed``, so every run serves the same roots in the same
 waves; ``--seed`` permutes them within consecutive blocks of
-``shuffle_block``.  A wave's time depends on its roots (the push edge
-budget ratchets to the largest frontier any of them has), so roots drawn
-afresh for each seed would change the work from run to run.
+``shuffle_block``.  A wave's time depends on its roots (they set how many
+levels it runs and how wide each level's frontier is, and so each push
+level's edge budget), so roots drawn afresh for each seed would change the
+work from run to run.
 """
 from __future__ import annotations
 

@@ -3,28 +3,34 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A cell (``workloads`` in ``BENCHMARK.json``) pairs a graph configuration
+A cell (``workloads`` in ``BENCHMARK.json``) pairs a configuration
 (``bench/configs/<config>.json``) with a traffic mix
 (``bench/traffic/<traffic>.json``, whose ``driver`` names a module in
 ``bench/drivers/``).  Each metric is a reader in ``bench/metrics/<name>.py``.
-Adding a configuration, mix or metric is adding files and entries; this
-file needs no edit.
+A configuration names its graph, its serving settings, the vertex program
+that serves it (``program``: a name ``repro.core.get_program`` knows, given
+to ``build_engine`` as ``algo``) and what its answers are (``output``: a
+module ``bench/outputs/<output>.py``, which says which vertices a row
+reaches, checks sampled rows against the plain reference and gives the
+control that must fail that check).  Adding a configuration, mix, output
+or metric is adding files and entries; this file needs no edit.
 
 The run: check that JAX sees a TPU with enough chips; load the graph from
 ``bench/cache/`` (generating it on the first run); warm every program shape
-the served waves can use; build the engine with
+the served waves can use; build the configuration's engine with
 ``repro.launch.serve.build_engine`` and serve it through
 ``repro.launch.dynbatch.DynamicBatcher``; let the mix's driver submit
-requests for ``--seconds`` and drain them; then compare a sample of the
-answered level rows, drawn from the seed, with the plain reference
-(``bench/reference.py``).  ``--trace 1`` profiles the window and reports
-the per-layer metrics instead of the end-to-end ones.
+requests for ``--seconds`` and drain them; then check a sample of the
+answered rows, drawn from the seed, with the configuration's output module.
+``--trace 1`` profiles the window and reports the per-layer metrics instead
+of the end-to-end ones.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and
 last ``checks``: each number compared with its limit).  The checks are also
 the last lines of stderr.  No TPU, too few chips, or any failure before the
-result: a non-zero exit and no result line.
+result (a configuration naming no known program or output among them): a
+non-zero exit and no result line.
 """
 from __future__ import annotations
 
@@ -50,7 +56,6 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import drivers  # noqa: E402
 import graph as bench_graph  # noqa: E402
-import reference  # noqa: E402
 from readings import latencies  # noqa: E402
 from roots import make_sampler  # noqa: E402
 
@@ -58,7 +63,6 @@ CHECK_SAMPLE = 32          # answered requests compared with the reference
 DRAIN_SECONDS = 150.0      # longest wait for answers after the window
 LADDER_TOP_SHARE = 8       # warm push budgets up to E / this (see warm_up)
 MAX_WAVE_LINES = 40        # waves described on stdout
-INF = reference.INF
 
 
 class RunFailed(RuntimeError):
@@ -89,15 +93,41 @@ def cell_metrics(manifest: dict, workload: str, trace: bool) -> list[dict]:
     return [m for m in group if workload in m.get("workloads", [workload])]
 
 
-def load_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
+def _load_module(kind: str, name: str):
+    path = BENCH / kind / f"{name}.py"
     if not path.exists():
-        raise RunFailed(f"no reader {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
+        raise RunFailed(f"no {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    return _load_module("metrics", name).read
+
+
+def load_output(name: str):
+    """The module ``bench/outputs/<name>.py``: ``reached``, ``check``,
+    ``control``."""
+    if not isinstance(name, str) or not name.replace("_", "").isalnum():
+        raise RunFailed(f"bad output name {name!r}")
+    return _load_module("outputs", name)
+
+
+def served_program(cfg: dict):
+    """(vertex program, output module) the configuration names."""
+    from repro.core import get_program
+    for key in ("program", "output"):
+        if key not in cfg:
+            raise RunFailed(f"configuration {cfg.get('name')!r} names no "
+                            f"{key!r}")
+    try:
+        program = get_program(cfg["program"])
+    except (TypeError, ValueError) as e:
+        raise RunFailed(str(e)) from None
+    return program, load_output(cfg["output"])
 
 
 # -- the device ---------------------------------------------------------------
@@ -170,34 +200,58 @@ class Recorder:
         return rows
 
 
-def build(indptr, indices):
+def build(indptr, indices, program):
     """The program's local engine over a CSR (symmetric: CSC is CSR)."""
     from repro.graph.csr import CSRGraph
     from repro.launch.serve import build_engine
     csr = CSRGraph(len(indptr) - 1, indptr, indices)
     engine, _ = build_engine(types.SimpleNamespace(csr=csr, csc=csr),
-                             distributed=False)
+                             algo=program.name, distributed=False)
     return engine
 
 
-def warm_up(n: int, num_arcs: int, slots: int) -> dict:
+def stand_in(n: int, num_arcs: int, undirected: bool):
+    """A CSR of ``n`` vertices and ``num_arcs`` arcs in which vertex 0 has
+    no arcs and a wave from vertex 1 reaches a dense pull level.
+
+    Directed: vertex 1 holds every arc, all to vertex 2.  Undirected (the
+    engine symmetrizes the graph and collapses repeated arcs, so the
+    stand-in must be a simple symmetric graph already): vertex 1 is joined
+    to every vertex from 2 on, and the other edges join vertices i and
+    i + j (mod n - 2) among them, j = 1, 2, ... as far as needed.  From
+    vertex 1 a push level reaches everything and the next level pulls."""
+    if not undirected:
+        indptr = np.zeros(n + 1, np.int64)
+        indptr[2:] = num_arcs
+        return indptr, np.full(num_arcs, 2, np.int32)
+    m = n - 2
+    extra = num_arcs // 2 - m
+    if num_arcs % 2 or not 0 <= extra <= m * ((m - 1) // 2):
+        raise RunFailed(f"no simple symmetric stand-in with {n} vertices "
+                        f"and {num_arcs} arcs")
+    step, i = np.divmod(np.arange(extra, dtype=np.int64), m)
+    src = np.concatenate([np.ones(m, np.int64), 2 + i])
+    dst = np.concatenate([np.arange(2, n, dtype=np.int64),
+                          2 + (i + step + 1) % m])
+    return bench_graph.csr_from_edges(src, dst, n)
+
+
+def warm_up(n: int, num_arcs: int, slots: int, program) -> dict:
     """Compile, outside the window, every program a served wave can run.
 
-    Program shapes depend only on |V|, |E|, the plane slots and the push
-    edge budget, so a stand-in graph of the same sizes warms them through
-    the engine's public entry (``run_batch(roots, budget=)``) in a few
-    one-level waves: vertex 0 has no arcs (a push level at the given
-    budget finds nothing), vertex 1 holds every arc (its frontier sends
-    the scheduler to one dense pull level).  The push budget doubles from
+    Program shapes depend only on the vertex program, |V|, |E|, the plane
+    slots and the push edge budget, so a stand-in graph of the same sizes
+    (``stand_in``) warms them through the engine's public entry
+    (``run_batch(roots, budget=)``) in a few short waves: vertex 0 has no
+    arcs (a push level at the given budget finds nothing), and a wave
+    from vertex 1 reaches a dense pull level.  The push budget doubles from
     the engine's ``init_budget`` while a level needs more; a push level
     never needs more than ``m_u / alpha`` arcs when it follows a push
     level, so warming up to ``E / LADDER_TOP_SHARE`` covers the ladder
     with room.  The run counts compilations in the window to show it.
     """
     t0 = time.monotonic()
-    indptr = np.zeros(n + 1, np.int64)
-    indptr[2:] = num_arcs
-    engine = build(indptr, np.full(num_arcs, 2, np.int32))
+    engine = build(*stand_in(n, num_arcs, program.undirected), program)
     budget = min(int(engine.init_budget), num_arcs + 1)
     rungs = []
     while True:
@@ -215,8 +269,8 @@ def warm_up(n: int, num_arcs: int, slots: int) -> dict:
 
 # -- one run ------------------------------------------------------------------
 
-def traversed_edges(deg: np.ndarray, row) -> int:
-    return int(deg[np.asarray(row) < INF].sum())
+def traversed_edges(deg: np.ndarray, reached) -> int:
+    return int(deg[reached].sum())
 
 
 def run_cell(cell: dict, cfg: dict, traffic: dict, metrics: list[dict],
@@ -226,12 +280,14 @@ def run_cell(cell: dict, cfg: dict, traffic: dict, metrics: list[dict],
              t_process: float = T_PROCESS, log=print, keep: dict | None = None):
     """One run of one cell; returns the result object for the last line.
 
-    ``keep``, where given, receives the checked sample (``roots``, ``rows``,
-    ``want``) and the graph (``indptr``, ``indices``)."""
+    ``keep``, where given, receives the checked sample (``roots``,
+    ``rows``), the graph (``indptr``, ``indices``) and each request's
+    latency (``latencies``)."""
     import jax
     from repro.launch.cache import REPO_CACHE_DIR
     from repro.launch.dynbatch import DynamicBatcher
 
+    program, output = served_program(cfg)
     info = device_info(jax)
     if require_tpu:
         require_chips(info, int(cell["chips"]))
@@ -249,14 +305,15 @@ def run_cell(cell: dict, cfg: dict, traffic: dict, metrics: list[dict],
     deg = np.diff(indptr)
     log(f"graph: {cfg['name']} {ginfo['graph_source']} in "
         f"{ginfo['graph_seconds']} s, {n} vertices, {num_arcs} arcs")
+    log(f"program: {program.name}, output: {cfg['output']}")
 
     serving = cfg["serving"]
     slots = -(-int(serving["max_batch"]) // 32) * 32
-    warm = warm_up(n, num_arcs, slots)
+    warm = warm_up(n, num_arcs, slots, program)
     log(f"warm_push_budgets: {warm['warm_push_budgets']}")
     log(f"warm_seconds: {warm['warm_seconds']}")
     t0 = time.monotonic()
-    engine = build(indptr, indices)
+    engine = build(indptr, indices, program)
     log(f"engine_build_seconds: {time.monotonic() - t0}")
     if wrap_engine is not None:
         engine = wrap_engine(engine)
@@ -304,7 +361,7 @@ def run_cell(cell: dict, cfg: dict, traffic: dict, metrics: list[dict],
     answers = [r.t_answer for r in ok]
     for r in ok:
         r.row = r.future.result(timeout=0)
-        r.traversed = traversed_edges(deg, r.row)
+        r.traversed = traversed_edges(deg, output.reached(r.row))
     late = [r.future.t_submit - r.t_due for r in requests]
     log(f"compilations_in_window: {n_compiles}")
     log(f"memory_peak_bytes: {peak}")
@@ -341,24 +398,21 @@ def run_cell(cell: dict, cfg: dict, traffic: dict, metrics: list[dict],
     rng = np.random.default_rng([seed, 1])
     pick = (rng.choice(len(ok), size=min(CHECK_SAMPLE, len(ok)),
                        replace=False) if ok else np.zeros(0, int))
+    roots = [ok[i].root for i in pick]
+    rows = [ok[i].row for i in pick]
     t0 = time.monotonic()
-    want = (reference.bfs_levels(indptr, indices, [ok[i].root for i in pick])
-            if pick.size else np.zeros((0, n), np.int32))
-    bad = reference.mismatches([ok[i].row for i in pick], want)
+    answer_checks = output.check(indptr, indices, roots, rows)
     log(f"reference_seconds: {time.monotonic() - t0}")
     if keep is not None:
         keep.update(latencies=[(r.t_due - t_window, x) for r, x in
                                zip(requests, lat)],
-                    roots=[ok[i].root for i in pick],
-                    rows=[ok[i].row for i in pick], want=want,
-                    indptr=indptr, indices=indices)
+                    roots=roots, rows=rows, indptr=indptr, indices=indices)
     failed = len(requests) - len(ok)
-    checks = {
-        "wrong_levels": {"value": bad, "limit": 0},
-        "unanswered": {"value": failed, "limit": 0},
-        "rows_checked_min": {"value": int(pick.size), "limit": 1},
-    }
-    correct = (bad <= 0 and failed <= 0 and pick.size >= 1)
+    checks = dict(answer_checks)
+    checks["unanswered"] = {"value": failed, "limit": 0}
+    checks["rows_checked_min"] = {"value": int(pick.size), "limit": 1}
+    correct = (all(c["value"] <= c["limit"] for c in answer_checks.values())
+               and failed <= 0 and pick.size >= 1)
     device = dict(info, memory_peak_bytes=peak)
     if summary is not None:
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])

@@ -6,8 +6,10 @@ They check the manifest and the files it names, the copied generators
 against the program's, the reference against the program's numpy oracle,
 the trace reduction on a trace recorded on a TPU v5e, the byte count of
 ``traversal_bw_share.batch`` by hand, that ``run.py`` refuses to run off a
-TPU, and that the comparison deciding ``correct`` fails for the control
-and for each fault a single-chip cell can have.
+TPU or for a configuration naming no known program or output, that the
+named program is the one warmed up and served, and that each output's
+check (``bench/outputs/``) passes the reference and fails its control and
+each fault a single-chip cell can have.
 """
 from __future__ import annotations
 
@@ -54,6 +56,8 @@ def test_manifest_names_resolve():
         data = json.loads((ROOT / cfg["file"]).read_text())
         assert data["name"] == cfg["name"]
         assert data["source"] == cfg["source"]
+        program, output = run.served_program(data)
+        assert callable(output.check) and callable(output.control)
         for key in cfg["reduced"]:
             assert data[key] != data["published"][key]
     for w in man["workloads"]:
@@ -131,6 +135,64 @@ def test_reference_agrees_with_the_oracle(generator):
         got = reference.bfs_levels(indptr, indices, roots)
         for r, row in zip(roots, got):
             np.testing.assert_array_equal(row, bfs_oracle(csr, int(r)))
+
+
+@pytest.mark.parametrize("generator", ["kron", "urand"])
+def test_parents_check_accepts_the_reference_not_the_control(generator):
+    parents = run.load_output("parents")
+    indptr, indices = bench_graph.generate(tiny_cfg(10, generator))
+    roots = np.flatnonzero(np.diff(indptr))[:32]
+    want = reference.bfs_parents(indptr, indices, roots)
+    levels = reference.bfs_levels(indptr, indices, roots)
+    for row, lvl in zip(want, levels):
+        np.testing.assert_array_equal(parents.reached(row), lvl < reference.INF)
+    got = parents.check(indptr, indices, roots, want)
+    assert list(got) == ["parent_not_an_edge", "parent_level_wrong",
+                         "tree_span_wrong"]
+    assert all(c == {"value": 0, "limit": 0} for c in got.values())
+    ctl = parents.check(indptr, indices, roots,
+                        parents.control(indptr, indices, roots))
+    assert ctl["parent_level_wrong"]["value"] > 0
+
+
+def _plant(fault, indptr, indices, root, row, level):
+    row = row.copy()
+    adj = lambda v: indices[indptr[v]:indptr[v + 1]]  # noqa: E731
+    at2 = np.flatnonzero(level == 2)
+    if fault == "two_cycle":
+        v = int(at2[0])
+        row[row[v]] = v
+    elif fault == "parent_not_adjacent":
+        v = next(int(v) for v in at2
+                 if np.setdiff1d(np.flatnonzero(level == 1), adj(v)).size)
+        row[v] = np.setdiff1d(np.flatnonzero(level == 1), adj(v))[0]
+    elif fault == "same_level_parent":
+        v, w = next((int(v), int(w)) for v in at2 for w in adj(v)
+                    if level[w] == 2)
+        row[v] = w
+    elif fault == "reached_dropped":
+        row[int(at2[0])] = -1
+    elif fault == "root_parent_elsewhere":
+        row[root] = adj(root)[0]
+    return row
+
+
+@pytest.mark.parametrize("fault,name", [
+    ("two_cycle", "parent_level_wrong"),
+    ("parent_not_adjacent", "parent_not_an_edge"),
+    ("same_level_parent", "parent_level_wrong"),
+    ("reached_dropped", "tree_span_wrong"),
+    ("root_parent_elsewhere", "tree_span_wrong"),
+])
+def test_parents_check_names_each_fault(fault, name):
+    parents = run.load_output("parents")
+    indptr, indices = bench_graph.generate(tiny_cfg(10))
+    root = int(np.argmax(np.diff(indptr)))
+    row = reference.bfs_parents(indptr, indices, [root])[0]
+    level = reference.bfs_levels(indptr, indices, [root])[0]
+    bad = _plant(fault, indptr, indices, root, row, level)
+    got = parents.check(indptr, indices, [root], [bad])
+    assert {k: c["value"] for k, c in got.items() if c["value"]} == {name: 1}
 
 
 def test_control_fails_the_comparison():
@@ -264,9 +326,10 @@ class Faulty:
 QUEUE_WAIT = {"name": "queue_wait_ms.served", "unit": "ms"}
 
 
-def _tiny_run(wrap=None, traffic="closed32", seed=2**33 + 5):
+def _tiny_run(wrap=None, traffic="closed32", seed=2**33 + 5, log=None,
+              **cfg_changes):
     man = run.load_manifest()
-    cfg = tiny_cfg(8)
+    cfg = dict(tiny_cfg(8), **cfg_changes)
     tr = json.loads((BENCH / "traffic" / f"{traffic}.json").read_text())
     if tr["driver"] == "poisson":
         tr["rate_per_s"] = 40.0
@@ -274,7 +337,7 @@ def _tiny_run(wrap=None, traffic="closed32", seed=2**33 + 5):
     return run.run_cell({"chips": 1}, cfg, tr, metrics, seed, 1.0,
                         False, require_tpu=False, wrap_engine=wrap,
                         cache_dir=Path(os.environ.get("TMPDIR", "/tmp"))
-                        / "bench-test-cache", log=lambda *a: None)
+                        / "bench-test-cache", log=log or (lambda *a: None))
 
 
 @pytest.mark.parametrize("traffic", ["closed32", "poisson-zipf"])
@@ -293,3 +356,66 @@ def test_fault_is_not_correct(kind):
     out = _tiny_run(wrap=lambda e: Faulty(e, kind))
     assert not out["correct"]
     assert out["checks"]["wrong_levels"]["value"] > 0
+
+
+@pytest.mark.parametrize("program", ["bfs", "sssp", "cc"])
+def test_configuration_names_the_served_program(program):
+    lines, served = [], []
+
+    def wrap(engine):
+        served.append(engine.program.name)
+        return engine
+
+    out = _tiny_run(wrap=wrap, log=lines.append, program=program)
+    assert out["correct"], out["checks"]
+    assert served == [program]
+    assert f"program: {program}, output: levels" in lines
+    assert "compilations_in_window: 0" in lines
+
+
+@pytest.mark.parametrize("changes", [
+    {"program": None}, {"program": "pagerank"}, {"program": ["bfs"]},
+    {"output": None}, {"output": "distances"}, {"output": "../run"},
+], ids=["no-program", "unknown-program", "program-not-a-name", "no-output",
+        "unknown-output", "output-a-path"])
+def test_configuration_without_a_known_program_or_output_is_refused(changes):
+    cfg = tiny_cfg(8)
+    for key, value in changes.items():
+        if value is None:
+            del cfg[key]
+        else:
+            cfg[key] = value
+    with pytest.raises(run.RunFailed):
+        run.run_cell({"chips": 1}, cfg, {}, [], 1, 1.0, False,
+                     require_tpu=False, log=lambda *a: None)
+
+
+class AsParents:
+    """The engine with its level rows turned into parent rows, so that a
+    run's ``correct`` comes from the parent check."""
+
+    def __init__(self, engine, indptr, indices):
+        self.engine, self.graph = engine, (indptr, indices)
+
+    num_vertices = property(lambda self: self.engine.num_vertices)
+    out_deg = property(lambda self: self.engine.out_deg)
+    last_stats = property(lambda self: self.engine.last_stats)
+
+    def run_batch(self, roots, **kw):
+        rows = self.engine.run_batch(roots, **kw)
+        return reference.parents_from_levels(*self.graph, roots, rows)
+
+
+@pytest.mark.parametrize("fault", [None, "state_unchanged", "half_batch"])
+def test_parent_rows_decide_correct(fault):
+    graph = bench_graph.generate(tiny_cfg(8))
+
+    def wrap(engine):
+        return AsParents(engine if fault is None else Faulty(engine, fault),
+                         *graph)
+
+    out = _tiny_run(wrap=wrap, output="parents")
+    assert out["correct"] == (fault is None), out["checks"]
+    assert list(out["checks"]) == ["parent_not_an_edge", "parent_level_wrong",
+                                   "tree_span_wrong", "unanswered",
+                                   "rows_checked_min"]
