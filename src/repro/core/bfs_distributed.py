@@ -594,10 +594,12 @@ class DistributedBFS:
             raise NotImplementedError(
                 "run_batch supports bitmap dispatch only: FIFO queues carry "
                 "scalar vertex IDs, not per-source masks")
-        if program.combine != "or":
+        if program.combine != "or" or program.payload:
             raise NotImplementedError(
-                "the distributed crossbar is an OR-reduce-scatter; "
-                f"program {program.name!r} wants combine={program.combine!r}")
+                "the distributed crossbar is an OR-reduce-scatter of plane "
+                f"words; program {program.name!r} wants "
+                f"combine={program.combine!r}, payload={program.payload!r} "
+                "(sharded parents are roadmap item R2)")
         # validate BEFORE the int64 cast (a float root must error, not
         # truncate); duplicates are allowed — one plane slot each
         roots = validate_roots(np.asarray(roots),

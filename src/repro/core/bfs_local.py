@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from collections import deque
 from functools import partial
 from typing import Protocol, runtime_checkable
 
@@ -73,8 +74,31 @@ class LocalGraph:
     in_seg_end: jax.Array    # int32[n_pad] last in-edge per child (-1: none)
 
 
+def _sorted_in_lists(csc: CSRGraph) -> CSRGraph:
+    """``csc`` with every in-list in ascending source order.
+
+    The dense pull's parent payload takes the first frontier arc of each
+    in-list as the parent, which is the smallest-id one only when the
+    lists are sorted (``csr_from_edges`` and ``transpose_csr`` build them
+    so; a hand-made CSC may not be).  One pass to check, a sort only when
+    some list descends."""
+    idx = np.asarray(csc.indices)
+    down = np.flatnonzero(idx[1:] < idx[:-1]) + 1
+    starts = np.zeros(idx.size + 1, bool)
+    starts[np.asarray(csc.indptr)] = True
+    if starts[down].all():
+        return csc
+    rows = np.repeat(np.arange(csc.num_vertices), np.diff(csc.indptr))
+    return CSRGraph(csc.num_vertices, csc.indptr,
+                    idx[np.lexsort((idx, rows))])
+
+
 def build_local_graph(csr: CSRGraph, csc: CSRGraph) -> LocalGraph:
     n = csr.num_vertices
+    if csc is not csr:
+        csc = _sorted_in_lists(csc)
+    else:
+        csr = csc = _sorted_in_lists(csr)
     n_pad = bitmap.num_words(n) * bitmap.WORD_BITS
 
     def pad_ptr(indptr):
@@ -427,10 +451,11 @@ def engine_num_vertices(engine) -> int | None:
 
 def count_traversed_edges(out_deg: np.ndarray, levels: np.ndarray) -> int:
     """Paper §VI-A GTEPS numerator: out-degrees of reached vertices, summed
-    over every source row of ``levels`` ([n] or [B, n]) — one masked
-    matvec instead of a python loop over rows."""
+    over every source row of ``levels`` ([n] or [B, n], level or parent
+    rows) — one masked matvec instead of a python loop over rows."""
     levels = np.atleast_2d(np.asarray(levels))
-    reached = levels < int(INF)                      # [B, n]
+    # level rows reach where < INF, parent rows where >= 0
+    reached = (levels >= 0) & (levels < int(INF))   # [B, n]
     return int((reached @ np.asarray(out_deg, dtype=np.int64)).sum())
 
 
@@ -460,3 +485,24 @@ def bfs_oracle(csr: CSRGraph, root: int) -> np.ndarray:
         lvl += 1
         level[frontier] = lvl
     return level
+
+
+def bfs_parent_oracle(csr: CSRGraph, root: int) -> np.ndarray:
+    """Plain-Python BFS parent tree — the oracle of the parent program.
+    Returns int64[n]: ``root`` at the root, -1 where unreached, else the
+    smallest-id in-neighbour one level closer to the root.  A deque BFS
+    over the CSR; it shares no code with the engines or ``bfs_oracle``."""
+    indptr, indices = csr.indptr.tolist(), csr.indices.tolist()
+    depth = [-1] * csr.num_vertices
+    parent = [-1] * csr.num_vertices
+    depth[root], parent[root] = 0, root
+    queue = deque([root])
+    while queue:
+        u = queue.popleft()
+        for v in indices[indptr[u]:indptr[u + 1]]:
+            if depth[v] < 0:
+                depth[v], parent[v] = depth[u] + 1, u
+                queue.append(v)
+            elif depth[v] == depth[u] + 1 and u < parent[v]:
+                parent[v] = u
+    return np.asarray(parent, np.int64)

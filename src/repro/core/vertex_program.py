@@ -11,7 +11,8 @@ are shared machinery, parameterized by a :class:`VertexProgram` bundle:
   root plus the per-vertex value array the program accumulates into.
 * ``commit(value, new_mask, lvl) -> value`` — the per-level apply: how a
   newly-discovered (vertex, plane) updates the value array (BFS/CC set the
-  level on first reach; SSSP takes a min-plus relaxation).
+  level on first reach; SSSP takes a min-plus relaxation; BFS parents
+  take the candidate parent the step found, see ``payload``).
 * ``combine`` — the plane merge op the fused propagate kernel and the
   distributed OR-reduce-scatter use ("or" for bit-planes; the kernel also
   implements "max" as the hook for payload planes — see
@@ -26,8 +27,9 @@ software analogue of keeping all 32 HBM pseudo-channels busy.
 
 Shipped instantiations: :class:`MultiSourceBFSRunner` (BFS, plus the
 legacy bool-plane baseline), :class:`ConnectedComponentsRunner` (multi-
-seed CC over the symmetrized graph) and :class:`SSSPRunner` (batched
-unit-weight shortest-path hop distances).  All three inherit the packed-
+seed CC over the symmetrized graph), :class:`SSSPRunner` (batched
+unit-weight shortest-path hop distances) and :class:`BFSParentsRunner`
+(Graph 500 kernel 2: BFS parent trees).  All four inherit the packed-
 word invariant (plane state never unpacks between P1 and the commit) and
 the one-sync-per-level driver (``host_transfers == iterations + 2``).
 """
@@ -35,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
+from functools import partial, wraps
 from typing import Callable
 
 import jax
@@ -73,6 +75,16 @@ def plane_seed_init(g: LocalGraph, roots: jax.Array):
     return frontier, frontier, value
 
 
+def parent_seed_init(g: LocalGraph, roots: jax.Array):
+    """Parent-plane init: the root planes of :func:`plane_seed_init`, and
+    a value plane of parents, -1 except each root its own parent."""
+    frontier, seen, _ = plane_seed_init(g, roots)
+    b = roots.shape[0]
+    value = jnp.full((g.n_pad, b), -1, jnp.int32)
+    value = value.at[roots, jnp.arange(b)].set(roots)
+    return frontier, seen, value
+
+
 def level_commit(value, new_mask, lvl):
     """BFS/CC apply: a vertex first reached at level ``lvl+1`` keeps it."""
     return jnp.where(new_mask, lvl + 1, value)
@@ -83,6 +95,21 @@ def minplus_commit(value, new_mask, lvl):
     lvl+1) over newly-relaxed planes.  With unit weights first arrival IS
     the minimum, so this converges in the same level-synchronous sweeps."""
     return jnp.minimum(value, jnp.where(new_mask, lvl + 1, INF))
+
+
+def parent_commit(value, new_mask, cand):
+    """Parent apply: a (vertex, plane) first reached this level takes the
+    candidate parent the step found (``cand``, see ``_edge_parents`` and
+    ``_scan_parents``)."""
+    return jnp.where(new_mask, cand, value)
+
+
+def level_reached(value):
+    return value < INF
+
+
+def parent_reached(value):
+    return value >= 0
 
 
 def frontier_drained(sv: np.ndarray) -> bool:
@@ -99,6 +126,14 @@ class VertexProgram:
     ``undirected=True`` means the algorithm's semantics require the
     symmetrized graph (engine builders symmetrize before ``build_local_
     graph``; the engine itself is orientation-agnostic).
+
+    ``payload="parents"`` makes the value plane a payload that rides the
+    arcs: every step also finds, for each newly reached (vertex, plane),
+    the smallest source id among the arcs that reached it, a min combine
+    over int32 ids, and ``commit(value, new_mask, cand)`` receives those
+    candidates in place of the level.  ``None`` (BFS, CC, SSSP) keeps the
+    steps exactly as they are without it.  ``reached`` says which values
+    of the finished rows count as reached (the TEPS numerator).
     """
 
     name: str
@@ -107,11 +142,18 @@ class VertexProgram:
     done: Callable = frontier_drained
     combine: str = "or"          # plane merge op (see kernels.msbfs_propagate)
     undirected: bool = False
+    reached: Callable = level_reached
+    payload: str | None = None
 
 
 BFS = VertexProgram(name="bfs")
 CC = VertexProgram(name="cc", undirected=True)
 SSSP = VertexProgram(name="sssp", commit=minplus_commit)
+# Graph 500 kernel 2: each row is a BFS parent tree (root -> itself, -1
+# where unreached, else the smallest-id in-neighbour one level up)
+BFS_PARENTS = VertexProgram(name="bfs_parents", init=parent_seed_init,
+                            commit=parent_commit, reached=parent_reached,
+                            payload="parents")
 
 
 class IntegrityError(RuntimeError):
@@ -150,7 +192,27 @@ class BudgetOverflowError(RuntimeError):
         self.need = int(need)
         self.retries = int(retries)
 
-PROGRAMS = {p.name: p for p in (BFS, CC, SSSP)}
+PROGRAMS = {p.name: p for p in (BFS, CC, SSSP, BFS_PARENTS)}
+
+
+def check_parent_rows(rows: np.ndarray, roots: np.ndarray) -> None:
+    """Parent rows must hold -1 or a vertex id in ``[0, n)`` everywhere,
+    and each plane's root must be its own parent.  Raises
+    :class:`IntegrityError`."""
+    rows = np.asarray(rows)
+    roots = np.asarray(roots)
+    bad = (rows < -1) | (rows >= rows.shape[1])
+    if bad.any():
+        b, v = (int(x) for x in np.argwhere(bad)[0])
+        raise IntegrityError(
+            f"{int(bad.sum())} parent values outside [-1, {rows.shape[1]})"
+            f" (first: plane {b}, vertex {v}, value {int(rows[b, v])})")
+    at_root = rows[np.arange(roots.size), roots]
+    if np.any(at_root != roots):
+        b = int(np.argwhere(at_root != roots)[0][0])
+        raise IntegrityError(
+            f"plane {b} lost its root: parent[{int(roots[b])}] = "
+            f"{int(at_root[b])}, expected the root itself")
 
 
 def get_program(name: str) -> VertexProgram:
@@ -213,11 +275,12 @@ def _integrity_chk(frontier_w, seen_w, nb: int):
 
 
 def _vp_statvec(g: LocalGraph, new_w, seen_w, total, overflow, nb: int,
-                chk=None):
+                chk=None, hits=None):
     """Fused per-level stats: scheduler inputs for the NEXT level, this
     step's edge total/overflow, and the discovery popcount, stacked into
     one int32[7] so the driver fetches a single array per level (int32[8]
-    with the integrity residue ``chk`` appended when checking is on).
+    with the integrity residue ``chk`` appended when checking is on; a
+    payload program appends last the rows its step wrote, ``hits``).
 
     ``nb`` is the TRUE batch size: the pad planes of the last word are
     unseen by construction, so masking with the padded width would make
@@ -236,16 +299,98 @@ def _vp_statvec(g: LocalGraph, new_w, seen_w, total, overflow, nb: int,
     ]
     if chk is not None:
         slots.append(jnp.asarray(chk, jnp.int32))
+    if hits is not None:
+        slots.append(jnp.asarray(hits, jnp.int32))
     return jnp.stack(slots)
 
 
 def _vp_commit(g: LocalGraph, program: VertexProgram, new_w, seen_w, value,
-               lvl, total, overflow, chk=None):
-    """Per-level apply (the pipeline's single unpack point) + fused stats."""
+               lvl, total, overflow, chk=None, payload=None):
+    """Per-level apply (the pipeline's single unpack point) + fused stats.
+    ``payload`` is a payload program's ``(cand, hits)``."""
     new_mask = bitmap.unpack_rows(new_w, value.shape[1])
-    value2 = program.commit(value, new_mask, lvl)
+    if payload is None:
+        value2, hits = program.commit(value, new_mask, lvl), None
+    else:
+        cand, hits = payload
+        value2 = program.commit(value, new_mask, cand)
     return value2, _vp_statvec(g, new_w, seen_w, total, overflow,
-                               value.shape[1], chk)
+                               value.shape[1], chk, hits)
+
+
+# a candidate-parent slot that no arc wrote
+NO_PARENT = np.iinfo(np.int32).max
+# payload rows written per pass of the parent loop: the update is at most
+# [PARENT_CHUNK, B] int32 whatever a level finds, and a pass costs about
+# its full size on the chip (rows past the count are dropped, not skipped)
+PARENT_CHUNK = 1 << 20
+
+
+def _write_parents(g: LocalGraph, hit, src, tgt, words, nb: int):
+    """Candidate parents from a list of arcs: ``cand[tgt[i], p]`` = the
+    smallest ``src[i]`` over the listed ``i`` with ``hit[i]`` and bit
+    ``p`` of ``words[i]``, ``NO_PARENT`` where no such arc is.
+
+    The hit arcs are compacted (one sort of the list's positions, hits
+    first) and scatter-min'ed ``PARENT_CHUNK`` rows a pass in a device
+    loop, so the step has one shape whatever a level finds and its update
+    is never ``[list, B]``.  Returns ``(cand, hits)``."""
+    m = hit.shape[0]
+    pos = jnp.arange(m, dtype=jnp.int32)
+    order = jax.lax.sort(jnp.where(hit, pos, pos + m))
+    hits = jnp.sum(hit, dtype=jnp.int32)
+    chunk = min(PARENT_CHUNK, m)
+
+    def write(k, cand):
+        # the last pass's start is clamped so it stays in bounds: it
+        # re-writes some rows of the pass before, which min makes harmless
+        i = jax.lax.dynamic_slice(order, (k * chunk,), (chunk,))
+        ok = i < m
+        i = jnp.where(ok, i, 0)
+        ids = jnp.where(bitmap.unpack_rows(words[i], nb), src[i][:, None],
+                        NO_PARENT)
+        return cand.at[jnp.where(ok, tgt[i], g.n_pad)].min(ids, mode="drop")
+
+    cand = jnp.full((g.n_pad, nb), NO_PARENT, jnp.int32)
+    return jax.lax.fori_loop(0, (hits + chunk - 1) // chunk, write,
+                             cand), hits
+
+
+def _edge_parents(g: LocalGraph, frontier_w, new_w, src, tgt, valid,
+                  nb: int):
+    """Candidate parents over an edge list (push, budgeted pulls): each
+    listed arc whose source is in the frontier of a plane its target is
+    new in writes its source id there."""
+    words = (frontier_w[jnp.maximum(src, 0)]
+             & new_w[jnp.where(valid, tgt, 0)])
+    hit = valid & bitmap.any_rows(words)
+    return _write_parents(g, hit, jnp.maximum(src, 0), tgt, words, nb)
+
+
+def _scan_parents(g: LocalGraph, msg, scan, new_w, nb: int):
+    """Candidate parents read off the dense pull's segmented OR-scan.
+
+    An arc is a first hit for plane ``p`` when its source is in ``p``'s
+    frontier (``msg``), no earlier arc of the same in-list's is (the scan
+    one slot back, within the segment) and its child is new in ``p``: one
+    arc per new (vertex, plane), which is the smallest-id frontier
+    in-neighbour because in-lists are sorted by source
+    (``build_local_graph``).  ``new`` reaches the arcs by a second scan
+    of the child's words set at its in-list's first arc (a gather of
+    ``new`` over every arc costs the chip about as much as the pull's own
+    gather).  Only the first-hit arcs are written (``_write_parents``)."""
+    if msg is None:                 # a graph without arcs
+        return jnp.full((g.n_pad, nb), NO_PARENT, jnp.int32), jnp.int32(0)
+    e = msg.shape[0]
+    same = (g.in_child[1:] == g.in_child[:-1])[:, None]
+    excl = jnp.concatenate([jnp.zeros_like(scan[:1]),
+                            jnp.where(same, scan[:-1], jnp.uint32(0))])
+    starts = jnp.where(g.in_deg > 0, g.in_indptr[:-1], e)
+    new_arc = bitmap.segment_or_rows(
+        jnp.zeros_like(msg).at[starts].set(new_w, mode="drop"), g.in_child)
+    first = msg & ~excl & new_arc
+    return _write_parents(g, bitmap.any_rows(first), g.in_indices,
+                          g.in_child, first, nb)
 
 
 def _propagate_edges(g: LocalGraph, frontier_w, seen_w, src, tgt, valid,
@@ -276,13 +421,16 @@ def _propagate_pull_scan(g: LocalGraph, frontier_w):
     """Candidate plane words for ALL vertices via the CSC edge stream:
     cand[v] = OR of frontier[parent] over v's in-list.  The edges are
     already grouped by child, so a segmented OR-scan + one gather at the
-    segment ends replaces the scatter entirely (packed words throughout)."""
+    segment ends replaces the scatter entirely (packed words throughout).
+    Returns ``(cand, msg, scan)``; the last two (None without arcs) are
+    the per-arc words and their scan, which the parent payload reads."""
     if g.in_indices.shape[0] == 0:
-        return jnp.zeros_like(frontier_w)
+        return jnp.zeros_like(frontier_w), None, None
     msg = frontier_w[g.in_indices]                  # [E, nw] packed gather
     scan = bitmap.segment_or_rows(msg, g.in_child)
     return jnp.where((g.in_seg_end >= 0)[:, None],
-                     scan[jnp.maximum(g.in_seg_end, 0)], jnp.uint32(0))
+                     scan[jnp.maximum(g.in_seg_end, 0)],
+                     jnp.uint32(0)), msg, scan
 
 
 def _propagate_pull_sparse(g: LocalGraph, frontier_w, seen_w, nb: int,
@@ -299,8 +447,9 @@ def _propagate_pull_sparse(g: LocalGraph, frontier_w, seen_w, nb: int,
     dense scan for full-stream levels (the expansion bookkeeping costs
     more per edge than the static-boundary scan).
 
-    Returns (new, seen2, total); ``total > budget`` means the step was
-    truncated and must be retried deeper (same overflow contract as push).
+    Returns (new, seen2, total, edges); ``total > budget`` means the step
+    was truncated and must be retried deeper (same overflow contract as
+    push); ``edges`` is the ``(parent, child, valid)`` list it expanded.
     """
     pmask = bitmap.plane_mask(nb)
     un_any = bitmap.any_rows(~seen_w & pmask)
@@ -326,31 +475,36 @@ def _propagate_pull_sparse(g: LocalGraph, frontier_w, seen_w, nb: int,
     cand = jnp.zeros((g.n_pad + 1, frontier_w.shape[1]), jnp.uint32)
     cand = cand.at[rows].set(scan[endpos], mode="drop")[:-1]
     new = cand & ~seen_w
-    return new, seen_w | new, total
+    return new, seen_w | new, total, (parent, child, valid)
 
 
-@jax.jit
-def _plane_traversed(g: LocalGraph, value):
+@partial(jax.jit, static_argnames=("program",))
+def _plane_traversed(g: LocalGraph, value, program: VertexProgram = BFS):
     """int32[B]: per-plane traversed edges = sum of out-degrees over the
     vertices each plane reached (the paper's TEPS numerator, one entry per
     source so pad planes can be sliced off without a host recount)."""
-    reached = value[: g.n] < INF
+    reached = program.reached(value[: g.n])
     return jnp.sum(jnp.where(reached, g.out_deg[: g.n, None], 0),
                    axis=0, dtype=jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("budget",))
-def _witness_check(g: LocalGraph, value, sample, budget: int):
+@partial(jax.jit, static_argnames=("budget", "parents"))
+def _witness_check(g: LocalGraph, value, sample, budget: int,
+                   parents: bool = False):
     """Sampled parent-witness audit, one fused reduction.
 
     For every sampled vertex ``v`` and plane ``p`` with a finite non-root
     value, SOME in-neighbor ``u`` must hold ``value[u,p] == value[v,p]-1``
     — the parent that discovered it (level-synchronous BFS/CC and
-    unit-weight SSSP all satisfy this exactly).  The K sampled in-lists
-    are expanded with the same budgeted owner-slot pattern as the sparse
-    pull, the witness predicate is OR-reduced per (vertex, plane), and the
-    result collapses to int32[2] = (violations, truncated) so it folds
-    into the run's final fetch (``host_transfers`` invariant intact).
+    unit-weight SSSP all satisfy this exactly).  On parent rows
+    (``parents``) the witness is the parent itself: for every reached
+    non-root ``v``, ``value[v,p]`` must be an in-neighbour ``u`` of ``v``
+    that is reached in ``p`` (``value[u,p] >= 0``).  The K sampled
+    in-lists are expanded with the same budgeted owner-slot pattern as
+    the sparse pull, the witness predicate is OR-reduced per (vertex,
+    plane), and the result collapses to int32[2] = (violations,
+    truncated) so it folds into the run's final fetch
+    (``host_transfers`` invariant intact).
     ``truncated != 0`` means the sampled in-lists overflowed ``budget``
     and the violation count is unusable — the driver skips, not raises.
     """
@@ -366,11 +520,16 @@ def _witness_check(g: LocalGraph, value, sample, budget: int):
     eidx = g.in_indptr[child] + (e - start)
     valid = (e < total) & (e < jnp.int32(budget))
     parent = g.in_indices[jnp.where(valid, eidx, 0)]
-    ok_e = valid[:, None] & (value[parent] == value[child] - 1)
+    if parents:
+        ok_e = valid[:, None] & (value[child] == parent[:, None]) & (
+            value[parent] >= 0)
+    else:
+        ok_e = valid[:, None] & (value[parent] == value[child] - 1)
     ok = jnp.zeros((k + 1, value.shape[1]), jnp.bool_)
     ok = ok.at[jnp.where(valid, owner_c, k)].max(ok_e, mode="drop")[:-1]
     vals = value[sample]                              # [K, B]
-    need = (vals > 0) & (vals < INF)
+    need = ((vals >= 0) & (vals != sample[:, None]) if parents
+            else (vals > 0) & (vals < INF))
     return jnp.stack([jnp.sum(need & ~ok, dtype=jnp.int32),
                       jnp.asarray(total > budget, jnp.int32)])
 
@@ -394,15 +553,15 @@ def vp_init_state(g: LocalGraph, roots: jax.Array, program: VertexProgram,
             _vp_statvec(g, frontier, seen, 0, 0, roots.shape[0], chk))
 
 
-@partial(jax.jit, static_argnames=("program", "budget", "use_pallas",
-                                   "tile_rows", "check"))
-def vp_push_step(g: LocalGraph, frontier_w, seen_w, value, lvl,
-                 program: VertexProgram, budget: int,
-                 use_pallas: bool = False, tile_rows: int | None = None,
-                 check: bool = False):
+def _push(g: LocalGraph, frontier_w, seen_w, value, lvl,
+          program: VertexProgram, budget: int,
+          use_pallas: bool = False, tile_rows: int | None = None,
+          check: bool = False):
     """Batched push on packed words: expand out-lists of any-plane
     frontier vertices; each budgeted edge carries its endpoint's packed
-    plane word straight into the candidate planes (fused P2->P3)."""
+    plane word straight into the candidate planes (fused P2->P3).  A
+    parent payload writes each budgeted edge's source id where the edge
+    reaches a new (vertex, plane) besides."""
     # the integrity residue is computed from the step's INPUT state: it
     # rides the output statvec but indicts the words the step consumed
     chk = (_integrity_chk(frontier_w, seen_w, value.shape[1]) if check
@@ -413,50 +572,76 @@ def vp_push_step(g: LocalGraph, frontier_w, seen_w, value, lvl,
                                           g.out_indices, budget)
     new, seen2 = _propagate_edges(g, frontier_w, seen_w, src, nbr, valid,
                                   use_pallas, program.combine, tile_rows)
+    payload = None
+    if program.payload:
+        payload = _edge_parents(g, frontier_w, new, src, nbr, valid,
+                                value.shape[1])
     value2, statvec = _vp_commit(g, program, new, seen2, value, lvl, total,
-                                 total > budget, chk)
+                                 total > budget, chk, payload)
     return new, seen2, value2, statvec
 
 
-@partial(jax.jit, static_argnames=("program", "budget", "use_pallas",
-                                   "tile_rows", "check"))
-def vp_pull_step(g: LocalGraph, frontier_w, seen_w, value, lvl,
-                 program: VertexProgram, budget: int = 0,
-                 use_pallas: bool = False, tile_rows: int | None = None,
-                 check: bool = False):
+def _pull(g: LocalGraph, frontier_w, seen_w, value, lvl,
+          program: VertexProgram, budget: int = 0,
+          use_pallas: bool = False, tile_rows: int | None = None,
+          check: bool = False):
     """Batched pull on packed words.
 
     Default path (``budget == 0``): dense segmented OR-scan over the whole
-    CSC edge stream (never overflows).  ``budget > 0`` selects the sparse
-    budgeted pull — only some-plane-unseen vertices' in-lists are expanded
-    (``_propagate_pull_sparse``), which the driver uses on tail levels
-    where m_u << E.  Pallas path: budgeted expansion through the fused
-    propagate kernel."""
-    chk = (_integrity_chk(frontier_w, seen_w, value.shape[1]) if check
-           else None)
+    CSC edge stream (never overflows); a parent payload reads its
+    candidates off that scan (``_scan_parents``).  ``budget > 0`` selects
+    the sparse budgeted pull — only some-plane-unseen vertices' in-lists
+    are expanded (``_propagate_pull_sparse``), which the driver uses on
+    tail levels where m_u << E.  Pallas path: budgeted expansion through
+    the fused propagate kernel.  The budgeted pulls find parents over the
+    edges they expanded, as push does."""
+    nb = value.shape[1]
+    chk = _integrity_chk(frontier_w, seen_w, nb) if check else None
+    edges = None
     if use_pallas:
-        un_any = bitmap.any_rows(
-            ~seen_w & bitmap.plane_mask(value.shape[1]))
+        un_any = bitmap.any_rows(~seen_w & bitmap.plane_mask(nb))
         active, _ = compact_indices(un_any, g.n_pad)
         child, parent, valid, total = expand_edges(
             active, g.in_indptr, g.in_indices, budget)
         new, seen2 = _propagate_edges(g, frontier_w, seen_w, parent, child,
                                       valid, True, program.combine,
                                       tile_rows)
+        edges = (parent, child, valid)
         overflow = total > budget
     elif budget:
-        new, seen2, total = _propagate_pull_sparse(
-            g, frontier_w, seen_w, value.shape[1], budget)
+        new, seen2, total, edges = _propagate_pull_sparse(
+            g, frontier_w, seen_w, nb, budget)
         overflow = total > budget
     else:
-        cand = _propagate_pull_scan(g, frontier_w)
+        cand, msg, scan = _propagate_pull_scan(g, frontier_w)
         new = cand & ~seen_w
         seen2 = seen_w | new
         total = jnp.int32(g.in_indices.shape[0])
         overflow = jnp.int32(0)
+    payload = None
+    if program.payload and edges is None:
+        payload = _scan_parents(g, msg, scan, new, nb)
+    elif program.payload:
+        payload = _edge_parents(g, frontier_w, new, *edges, nb)
     value2, statvec = _vp_commit(g, program, new, seen2, value, lvl, total,
-                                 overflow, chk)
+                                 overflow, chk, payload)
     return new, seen2, value2, statvec
+
+
+def _jit_step(body, name: str):
+    """``body`` jitted under its own name: the device trace names each
+    program after the function jit wraps, so the parent program's steps
+    (``*_parents``, the same bodies) read apart from the others'."""
+    fn = wraps(body)(lambda *a, **kw: body(*a, **kw))
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, static_argnames=("program", "budget", "use_pallas",
+                                        "tile_rows", "check"))
+
+
+vp_push_step = _jit_step(_push, "vp_push_step")
+vp_pull_step = _jit_step(_pull, "vp_pull_step")
+vp_push_step_parents = _jit_step(_push, "vp_push_step_parents")
+vp_pull_step_parents = _jit_step(_pull, "vp_pull_step_parents")
 
 
 def vp_reference(g: LocalGraph, roots, program: VertexProgram = BFS,
@@ -473,11 +658,15 @@ def vp_reference(g: LocalGraph, roots, program: VertexProgram = BFS,
 
     def body(state):
         frontier, seen, value, lvl = state
-        cand = _propagate_pull_scan(g, frontier)
+        cand, msg, scan = _propagate_pull_scan(g, frontier)
         new = cand & ~seen
         seen = seen | new
         new_mask = bitmap.unpack_rows(new, roots.shape[0])
-        value = program.commit(value, new_mask, lvl)
+        if program.payload:
+            parents, _ = _scan_parents(g, msg, scan, new, roots.shape[0])
+            value = program.commit(value, new_mask, parents)
+        else:
+            value = program.commit(value, new_mask, lvl)
         return new, seen, value, lvl + 1
 
     frontier, seen, value, lvl = jax.lax.while_loop(
@@ -522,6 +711,11 @@ class VertexProgramResult:
     @property
     def distances(self) -> np.ndarray:
         """SSSP alias: the value rows are hop distances."""
+        return self.levels
+
+    @property
+    def parents(self) -> np.ndarray:
+        """BFS-parents alias: the value rows are parent trees."""
         return self.levels
 
     @property
@@ -660,7 +854,11 @@ class VertexProgramRunner:
     def _guard_rows(self, rows: np.ndarray, roots: np.ndarray,
                     iters: int) -> None:
         """Final value rows must be 0 at each plane's own root and either
-        INF or bounded by the iteration count everywhere else."""
+        INF or bounded by the iteration count everywhere else (parent
+        rows: :func:`check_parent_rows`)."""
+        if self.program.payload == "parents":
+            check_parent_rows(rows, roots)
+            return
         bad = (rows != int(INF)) & ((rows < 0) | (rows > iters))
         if bad.any():
             v = int(np.argwhere(bad)[0][1])
@@ -736,6 +934,8 @@ class VertexProgramRunner:
         push_iters = pull_iters = 0
         overflow_retries = 0
         budget = floor              # largest budget any level used
+        payload_meta = ({"payload": program.payload} if program.payload
+                        else {})
         t0 = time.perf_counter()
         with span("vp.init"):
             frontier, seen, value, statvec = vp_init_state(
@@ -747,7 +947,8 @@ class VertexProgramRunner:
         while not program.done(sv):
             # vp.level.host: the host's work from the statvec to the return
             # of the next step's dispatch
-            with span("vp.level.host", level=lvl, retry=0) as host:
+            with span("vp.level.host", level=lvl, retry=0,
+                      **payload_meta) as host:
                 mode = choose_mode_host(self.sched, mode, int(sv[SV_NF]),
                                         int(sv[SV_MF]), int(sv[SV_MU]), g.n,
                                         int(sv[SV_NU]))
@@ -767,7 +968,11 @@ class VertexProgramRunner:
                     step_budget = max(floor, _next_pow2(min(need, cap + 1)))
                 elif self.sparse_pull:
                     step_budget = self._pull_budget(int(sv[SV_MU]))
-                step = vp_push_step if mode == PUSH else vp_pull_step
+                if program.payload:
+                    step = (vp_push_step_parents if mode == PUSH
+                            else vp_pull_step_parents)
+                else:
+                    step = vp_push_step if mode == PUSH else vp_pull_step
                 if corrupt is not None and lvl == int(corrupt[0]):
                     # chaos hook: flip one frontier plane bit, exact-once
                     frontier = _xor_plane_bit(frontier, corrupt[1],
@@ -785,7 +990,7 @@ class VertexProgramRunner:
             while step_budget and bool(sv[SV_OVERFLOW]):
                 retries += 1
                 with span("vp.level.host", level=lvl, retry=retries,
-                          mode=MODE_NAMES[mode]) as host:
+                          mode=MODE_NAMES[mode], **payload_meta) as host:
                     if check:
                         self._guard_sv(sv, lvl, b, sum(pcs))
                     overflow_retries += 1   # surfaced in last_stats
@@ -806,6 +1011,10 @@ class VertexProgramRunner:
             levels.append(dict(mode=MODE_NAMES[mode], budget=step_budget,
                                need=need, total=int(sv[SV_TOTAL]),
                                retries=retries))
+            if program.payload:
+                # the arcs the step's payload wrote: edges that reach a
+                # new (vertex, plane), or the dense pull's first hits
+                levels[-1]["hits"] = int(sv[-1])
             pcs.append(int(sv[SV_COUNT]))
             lvl += 1
             inspected += int(sv[SV_TOTAL])
@@ -828,19 +1037,22 @@ class VertexProgramRunner:
                 sample = jnp.asarray(
                     self._witness_rng.integers(0, g.n, size=k), jnp.int32)
                 rows_cm, trav_np, wit = self._fetch_many(
-                    value[: g.n], _plane_traversed(g, value),
-                    _witness_check(g, value, sample, self.witness_budget))
+                    value[: g.n], _plane_traversed(g, value, program),
+                    _witness_check(g, value, sample, self.witness_budget,
+                                   program.payload == "parents"))
             else:
-                rows_cm, trav_np = self._fetch_pair(value[: g.n],
-                                                    _plane_traversed(g, value))
+                rows_cm, trav_np = self._fetch_pair(
+                    value[: g.n], _plane_traversed(g, value, program))
             rows = rows_cm.T                             # [B, n]
             if check:
                 self._guard_rows(rows, roots, lvl)
                 if wit is not None and not int(wit[1]) and int(wit[0]):
                     raise IntegrityError(
                         f"witness audit failed: {int(wit[0])} sampled "
-                        "(vertex, plane) discoveries have no in-neighbor at "
-                        "value - 1")
+                        "(vertex, plane) discoveries have no "
+                        + ("reached in-neighbor as parent"
+                           if program.payload else
+                           "in-neighbor at value - 1"))
         res = self._result(rows, b, lvl, inspected, push_iters,
                            pull_iters, dt, overflow_retries, budget,
                            trav_vec=trav_np)
@@ -1109,3 +1321,22 @@ class SSSPRunner(VertexProgramRunner):
     """
 
     program = SSSP
+
+
+# ---------------------------------------------------------------------------
+# Instantiation 4: batched BFS parent trees (Graph 500 kernel 2).
+# ---------------------------------------------------------------------------
+
+class BFSParentsRunner(VertexProgramRunner):
+    """Batched BFS that answers each root with its BFS parent tree.
+
+    One frontier plane per root, as BFS; the value plane carries parents
+    (the ``parents`` payload): ``result.parents[b, root_b] == root_b``, -1
+    where unreached, and every other reached vertex's parent is its
+    smallest-id in-neighbour one level closer to the root, which makes the
+    rows deterministic and exactly comparable with a plain oracle
+    (``bfs_local.bfs_parent_oracle``).  The parent tree's depths are the
+    BFS levels, so the Graph 500 validation rules hold by construction.
+    """
+
+    program = BFS_PARENTS

@@ -10,7 +10,8 @@ from repro.ft.failures import (FailureInjector, InjectedFailure, StepTimer,
                                run_with_retries)
 from repro.ft.integrity import (INTEGRITY_MODES, IntegrityConfig,
                                 IntegrityError, check_level_rows,
-                                check_popcount_sequence)
+                                check_parent_rows, check_popcount_sequence,
+                                check_rows)
 from repro.ft.supervisor import (DETERMINISTIC, FAULT_KINDS, TRANSIENT,
                                  EngineSupervisor, FaultPlan, FaultyEngine,
                                  KernelFault, PoisonedRoot,
@@ -29,5 +30,6 @@ __all__ = [
     "TRANSIENT", "DETERMINISTIC", "classify_fault", "is_kernel_fault",
     "find_tunable_engine", "supports_budget_override",
     "INTEGRITY_MODES", "IntegrityConfig", "IntegrityError",
-    "check_level_rows", "check_popcount_sequence",
+    "check_level_rows", "check_parent_rows", "check_popcount_sequence",
+    "check_rows",
 ]

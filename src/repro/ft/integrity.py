@@ -15,8 +15,9 @@ layered from cheapest to strongest (see ``INTEGRITY_MODES``):
 2. **Host-side protocol checks** (also ``invariants``) — per-level
    discovery popcounts must be positive-then-terminate, cumulative
    discoveries bounded by |V| x planes, final value rows bounded by the
-   iteration count with each plane's own root at 0
-   (:func:`check_level_rows`, :func:`check_popcount_sequence`).
+   iteration count with each plane's own root at 0, or parent rows of
+   vertex ids with each root its own parent (:func:`check_rows`,
+   :func:`check_popcount_sequence`).
 3. **Sampled witness audit** (mode ``witness``) — for K sampled
    discovered vertices per wave, verify ON DEVICE that some in-neighbor
    sits exactly one level closer (the parent that discovered it).  One
@@ -39,11 +40,13 @@ import dataclasses
 import numpy as np
 
 from repro.core.bfs_local import INF
-from repro.core.vertex_program import INTEGRITY_MODES, IntegrityError
+from repro.core.vertex_program import (INTEGRITY_MODES, IntegrityError,
+                                       check_parent_rows)
 
 __all__ = [
     "INTEGRITY_MODES", "IntegrityConfig", "IntegrityError",
-    "check_level_rows", "check_popcount_sequence",
+    "check_level_rows", "check_parent_rows", "check_popcount_sequence",
+    "check_rows",
 ]
 
 
@@ -101,6 +104,17 @@ def check_level_rows(rows: np.ndarray, roots: np.ndarray,
         raise IntegrityError(
             f"plane {b} lost its root: value[{int(roots[b])}] = "
             f"{int(at_root[b])}, expected 0")
+
+
+def check_rows(rows: np.ndarray, roots: np.ndarray,
+               iterations: int | None = None, program=None) -> None:
+    """Row validation by what the rows are: :func:`check_parent_rows` for
+    a program with the ``parents`` payload (-1 or a vertex id, each root
+    its own parent), else :func:`check_level_rows`."""
+    if getattr(program, "payload", None) == "parents":
+        check_parent_rows(rows, roots)
+    else:
+        check_level_rows(rows, roots, iterations)
 
 
 def check_popcount_sequence(pcs) -> None:

@@ -54,7 +54,7 @@ from repro.core import bitmap
 from repro.core.bfs_local import engine_num_vertices
 from repro.core.vertex_program import BudgetOverflowError, IntegrityError
 from repro.ft.failures import InjectedFailure, StepTimer
-from repro.ft.integrity import (IntegrityConfig, check_level_rows,
+from repro.ft.integrity import (IntegrityConfig, check_rows,
                                 check_popcount_sequence)
 
 # ---------------------------------------------------------------------------
@@ -304,6 +304,8 @@ class EngineSupervisor:
         self.sleep = time.sleep if sleep is None else sleep
         self._supports_budget = supports_budget_override(engine)
         self._tunable = find_tunable_engine(engine)
+        # what the rows are (levels or parent trees) decides their check
+        self._program = getattr(self._tunable or engine, "program", None)
         if isinstance(integrity, str):
             integrity = IntegrityConfig(mode=integrity)
         self.integrity = integrity
@@ -559,15 +561,16 @@ class EngineSupervisor:
         """Host-side answer validation for one successful attempt; raises
         :class:`IntegrityError` (kernel-class, so _serve retries/demotes).
 
-        Row bounds + root-zero run on every wave (this is the check that
-        catches RESULT corruption the in-flight statvec slots cannot see);
+        Row bounds + root-zero (parent rows: ids + root-to-itself) run on
+        every wave (this is the check that catches RESULT corruption the
+        in-flight statvec slots cannot see);
         popcount positive-then-terminate runs when the engine recorded the
         sequence; mode ``audit`` additionally re-runs a sampled fraction
         of waves through the reference rung (packed off, else pallas off)
         and compares rows exactly.
         """
         self._n_integrity_checks += 1
-        check_level_rows(rows, roots, stats.get("iterations"))
+        check_rows(rows, roots, stats.get("iterations"), self._program)
         pcs = stats.get("discovery_popcounts")
         if pcs is not None:
             check_popcount_sequence(pcs)

@@ -24,7 +24,8 @@ TEPS.
 
 Other vertex programs serve through the same batcher — ``--algo cc`` /
 ``--algo sssp`` run batched connected components / unit-weight SSSP waves
-over the same plane-packed engine:
+over the same plane-packed engine, and ``--algo bfs_parents`` answers
+each root with its BFS parent tree (Graph 500 kernel 2):
 
   PYTHONPATH=src python -m repro.launch.serve --algo cc --bfs-requests 32
 """
@@ -104,8 +105,10 @@ def build_engine(graph, *, algo: str = "bfs",
     ``graph`` is a dataset name (``repro.graph.DATASETS``) or a
     ``repro.graph.datasets.Dataset`` the caller already generated.
 
-    ``algo``: "bfs" | "cc" | "sssp" (the shipped vertex programs — CC
-    symmetrizes the graph first, components being an undirected notion).
+    ``algo``: "bfs" | "cc" | "sssp" | "bfs_parents" (the shipped vertex
+    programs — CC symmetrizes the graph first, components being an
+    undirected notion; ``bfs_parents`` answers Graph 500 parent rows and
+    runs on one device only: sharded parents are roadmap item R2).
     Returns (engine, out_degrees) where the degrees are those of the graph
     actually traversed (symmetrized for CC).  Single device -> the local
     runner for the program; multi-device -> ``DistributedBFS`` carrying
@@ -118,21 +121,26 @@ def build_engine(graph, *, algo: str = "bfs",
     instead of scanning the whole CSC stream — the paper's actual pull
     semantics); the distributed engine ignores it for now.
     """
-    from repro.core import (ConnectedComponentsRunner, MultiSourceBFSRunner,
-                            SSSPRunner, build_local_graph, get_program,
-                            partition_graph)
+    from repro.core import (BFSParentsRunner, ConnectedComponentsRunner,
+                            MultiSourceBFSRunner, SSSPRunner,
+                            build_local_graph, get_program, partition_graph)
     from repro.graph import get_dataset, symmetrize_csr
 
     program = get_program(algo)
+    n_dev = jax.device_count()
+    if distributed is None:
+        distributed = n_dev > 1
+    if distributed and program.payload:
+        raise ValueError(
+            f"vertex program {algo!r} runs on one device only: the sharded "
+            "engine combines plane words by OR and carries no payload "
+            "(sharded parents are roadmap item R2)")
     ds = get_dataset(graph) if isinstance(graph, str) else graph
     csr, csc = ds.csr, ds.csc
     if program.undirected:
         csr = symmetrize_csr(csr)
         csc = csr            # a symmetrized graph is its own transpose
     deg = np.diff(csr.indptr)
-    n_dev = jax.device_count()
-    if distributed is None:
-        distributed = n_dev > 1
     if distributed:
         from repro.compat import make_mesh
         from repro.core.bfs_distributed import DistributedBFS
@@ -141,7 +149,8 @@ def build_engine(graph, *, algo: str = "bfs",
         return DistributedBFS(pg, mesh, program=program), deg
     runner_cls = {"bfs": MultiSourceBFSRunner,
                   "cc": ConnectedComponentsRunner,
-                  "sssp": SSSPRunner}[algo]
+                  "sssp": SSSPRunner,
+                  "bfs_parents": BFSParentsRunner}[algo]
     return runner_cls(build_local_graph(csr, csc),
                       sparse_pull=sparse_pull), deg
 
@@ -202,7 +211,8 @@ def serve_bfs(graph: str, batch: int, seed: int = 0,
     out = bfs_batch(roots, engine=engine, out_deg=deg)
     levels = out.pop("levels")
     out.update(graph=graph, algo=algo,
-               reached_mean=float((levels < (1 << 30)).sum(1).mean()))
+               reached_mean=float(((levels >= 0) & (levels < (1 << 30)))
+                                  .sum(1).mean()))
     return out
 
 
@@ -354,7 +364,7 @@ def main():
     ap.add_argument("--bfs-graph",
                     help="serve batched graph queries over this graph "
                          "instead of LM")
-    ap.add_argument("--algo", choices=("bfs", "cc", "sssp"),
+    ap.add_argument("--algo", choices=("bfs", "cc", "sssp", "bfs_parents"),
                     help="vertex program to serve (implies graph serving "
                          "through the dynamic batcher; default graph "
                          "small-12-8 when --bfs-graph is omitted)")

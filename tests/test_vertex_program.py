@@ -357,13 +357,13 @@ def test_sparse_pull_matches_dense_scan_unit():
     frontier[g.n:] = 0                      # pad vertices carry no state
     seen[g.n:] = pmask                      # pad vertices: all planes seen
 
-    dense_new = np.asarray(_propagate_pull_scan(g, frontier)) & ~seen
+    dense_new = np.asarray(_propagate_pull_scan(g, frontier)[0]) & ~seen
     # exact unseen-edge total: sum of in-degrees over any-plane-unseen
     in_deg = np.diff(np.asarray(g.in_indptr))[: g.n_pad]
     un_any = ((~seen & pmask) != 0).any(axis=1)
     m_u = int(in_deg[un_any].sum())
 
-    new, seen2, total = _propagate_pull_sparse(
+    new, seen2, total, _ = _propagate_pull_sparse(
         g, frontier, seen, nb, max(1 << (m_u - 1).bit_length(), 64))
     assert int(total) == m_u
     np.testing.assert_array_equal(np.asarray(new), dense_new)
@@ -371,8 +371,8 @@ def test_sparse_pull_matches_dense_scan_unit():
 
     # truncated budget: total still reports m_u so the driver retries
     if m_u > 4:
-        _, _, short = _propagate_pull_sparse(g, frontier, seen, nb,
-                                             m_u // 2)
+        _, _, short, _ = _propagate_pull_sparse(g, frontier, seen, nb,
+                                                m_u // 2)
         assert int(short) == m_u
 
 
