@@ -180,20 +180,55 @@ def compact_indices(mask: jax.Array, cap: int) -> tuple[jax.Array, jax.Array]:
     return idx.astype(jnp.int32), jnp.sum(mask, dtype=jnp.int32)
 
 
+OWNER_MERGE_RATIO = 16
+
+
+def owner_path(n: int, budget: int) -> str:
+    """How :func:`expand_edges` finds the owners of ``budget`` slots over
+    ``n`` active entries, from those static shapes alone.
+
+    ``"search"`` is ``jnp.searchsorted``: a loop of ceil(log2(n + 1))
+    rounds, each a gather of ``budget`` entries of ``cum``.  ``"merge"``
+    is one scatter-add of the ``n`` cumulative degrees into ``budget + 1``
+    bins and a cumsum: cheaper once ``budget * OWNER_MERGE_RATIO >= n``
+    (the crossover measured on a TPU v5e at n = 2^22, PERF.md §5)."""
+    return "merge" if budget * OWNER_MERGE_RATIO >= n else "search"
+
+
+def search_owners(cum: jax.Array, budget: int) -> jax.Array:
+    """``owner[e] = #{k : cum[k] <= e}`` for each slot ``e < budget``,
+    by binary search (``cum`` non-decreasing)."""
+    e = jnp.arange(budget, dtype=jnp.int32)
+    return jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
+
+
+def merge_owners(cum: jax.Array, budget: int) -> jax.Array:
+    """:func:`search_owners`, bit for bit, by one merge: slots and ``cum``
+    are both sorted, so counting the ``cum`` entries in each slot's bin
+    (those past the budget share the last bin) and summing the counts
+    gives every slot its owner."""
+    hist = jnp.zeros(budget + 1, jnp.int32).at[jnp.minimum(cum, budget)].add(
+        1, indices_are_sorted=True, mode="promise_in_bounds")
+    return jnp.cumsum(hist)[:budget]
+
+
 def expand_edges(active: jax.Array, indptr: jax.Array, indices: jax.Array,
                  budget: int):
     """P2 neighbor gather: flatten the neighbor lists of ``active`` vertices.
 
     Returns (sources, neighbors, valid, total_edges).  ``total_edges`` may
     exceed ``budget`` — the caller must treat that as overflow and retry with
-    a bigger budget (the HBM-reader queue depth analogue).
+    a bigger budget (the HBM-reader queue depth analogue).  Each slot's
+    owner comes by the path :func:`owner_path` picks for the shapes.
     """
     a = jnp.maximum(active, 0)
     deg = (indptr[a + 1] - indptr[a]) * (active >= 0)
     cum = jnp.cumsum(deg)
     total = cum[-1]
     e = jnp.arange(budget, dtype=jnp.int32)
-    owner = jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
+    owners = (merge_owners if owner_path(active.shape[0], budget) == "merge"
+              else search_owners)
+    owner = owners(cum, budget)
     owner_c = jnp.minimum(owner, active.shape[0] - 1)
     start = cum[owner_c] - deg[owner_c]
     src = active[owner_c]

@@ -48,7 +48,7 @@ from repro.core import bitmap
 from repro.core.bfs_local import (INF, SV_COUNT, SV_MF, SV_MU, SV_NF,
                                   SV_NU, SV_OVERFLOW, SV_TOTAL, LocalGraph,
                                   compact_indices, count_traversed_edges,
-                                  expand_edges, validate_roots)
+                                  expand_edges, owner_path, validate_roots)
 from repro.core.scheduler import (PUSH, SchedulerConfig, choose_mode,
                                   choose_mode_host)
 from repro.spans import span
@@ -883,6 +883,13 @@ class VertexProgramRunner:
         pb = max(1 << 12, _next_pow2(m_u))
         return pb if pb * 8 <= cap else 0
 
+    def _owner_meta(self, expands: bool, budget: int) -> dict:
+        """``{"owner": "merge" | "search"}``, the owner path of the
+        level's ``expand_edges`` (over all ``n_pad`` vertex slots), for a
+        step that expands; else empty."""
+        return ({"owner": owner_path(self.g.n_pad, budget)} if expands
+                else {})
+
     def run(self, roots, *, budget: int | None = None) -> VertexProgramResult:
         # validate BEFORE the int32 cast: a >= 2**31 root must error, not
         # wrap.  This is the shared entry — every algorithm goes through it.
@@ -981,7 +988,8 @@ class VertexProgramRunner:
                 # retry from the PRE-step seen: an overflowed (truncated)
                 # step may have committed a partial discovery set
                 state0 = (frontier, seen, value)
-                host.set_metadata(mode=MODE_NAMES[mode], budget=step_budget)
+                host.set_metadata(mode=MODE_NAMES[mode], budget=step_budget,
+                                  **self._owner_meta(budgeted, step_budget))
                 frontier, seen, value, statvec = step(
                     g, *state0, np.int32(lvl), program, step_budget,
                     self.use_pallas, self.tile_rows, check=check)
@@ -999,7 +1007,9 @@ class VertexProgramRunner:
                         raise BudgetOverflowError(step_budget, int(sv[SV_MF]),
                                                   overflow_retries)
                     step_budget *= 2   # HBM-reader queue overflow: deepen
-                    host.set_metadata(budget=step_budget)
+                    host.set_metadata(budget=step_budget,
+                                      **self._owner_meta(budgeted,
+                                                         step_budget))
                     frontier, seen, value, statvec = step(
                         g, *state0, np.int32(lvl), program, step_budget,
                         self.use_pallas, self.tile_rows, check=check)
@@ -1010,7 +1020,8 @@ class VertexProgramRunner:
                 budget = max(budget, step_budget)
             levels.append(dict(mode=MODE_NAMES[mode], budget=step_budget,
                                need=need, total=int(sv[SV_TOTAL]),
-                               retries=retries))
+                               retries=retries,
+                               **self._owner_meta(budgeted, step_budget)))
             if program.payload:
                 # the arcs the step's payload wrote: edges that reach a
                 # new (vertex, plane), or the dense pull's first hits

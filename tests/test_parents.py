@@ -198,7 +198,8 @@ def test_level_records_count_the_payload_rows():
     recs = eng.last_stats["levels"]
     for r in recs:
         assert set(r) == {"mode", "budget", "need", "total", "retries",
-                          "hits"}
+                          "hits"} | ({"owner"} if r["mode"] == "push"
+                                     else set())
         if r["mode"] == "push":      # edges that reach a new pair
             assert r["hits"] <= min(r["total"], r["budget"])
     # a dense pull writes one arc per new (vertex, plane) at most, and at

@@ -29,7 +29,7 @@ import repro.core.vertex_program as vp
 from repro.core import (ConnectedComponentsRunner, MultiSourceBFSRunner,
                         SSSPRunner, bfs_oracle, build_local_graph,
                         msbfs_reference)
-from repro.core.bfs_local import SV_OVERFLOW
+from repro.core.bfs_local import SV_OVERFLOW, owner_path
 from repro.graph import (csr_from_edges, rmat_edges, symmetrize_csr,
                          transpose_csr)
 from repro.launch.dynbatch import DynamicBatcher
@@ -115,7 +115,7 @@ EXPECTED_ARGS = {
     "vp.wave": {"slots", "budget"},
     "vp.init": set(),
     "vp.sync": {"level", "retry"},
-    "vp.level.host": {"level", "retry", "mode", "budget"},
+    "vp.level.host": {"level", "retry", "mode", "budget", "owner"},
     "vp.rows": {"slots"},
     "dynbatch.submit": {"req"},
     "dynbatch.cut": {"wave", "batch", "preempted"},
@@ -157,6 +157,10 @@ def test_one_level_host_span_per_level_plus_retries(traced):
     for s in host:
         rec = st["levels"][s["args"]["level"]]
         assert s["args"]["mode"] == rec["mode"]
+        # the owner path of the rung this dispatch ran (a re-run's own)
+        assert s["args"].get("owner") == (
+            owner_path(traced["g"].n_pad, s["args"]["budget"])
+            if rec["mode"] == "push" else None)
     syncs = _runner_spans(traced, "vp.sync")
     # the init's statvec, one per level, one per re-run
     assert len(syncs) == 1 + len(host)
@@ -204,11 +208,14 @@ def _check_levels(st):
     assert sum(r["retries"] for r in recs) == st["overflow_retries"]
     assert sum(r["total"] for r in recs) == st["edges_inspected"]
     for r in recs:
-        assert set(r) == {"mode", "budget", "need", "total", "retries"}
+        push = r["mode"] == "push"
+        assert set(r) == {"mode", "budget", "need", "total", "retries"} | (
+            {"owner"} if push else set())   # only push expands edges
         assert all(isinstance(r[k], int)
                    for k in ("budget", "need", "total", "retries"))
-        if r["mode"] == "push":
+        if push:
             assert r["need"] <= r["budget"] and r["total"] <= r["budget"]
+            assert r["owner"] in ("merge", "search")
         else:
             assert r["budget"] == 0        # the dense pull has no budget
 
@@ -283,8 +290,9 @@ def test_override_is_the_floor_of_an_empty_push_level(kron, rung):
     eng = MultiSourceBFSRunner(g)
     eng.run_batch(np.full(32, isolated[0]), budget=rung)
     floor = min(rung, int(csr.indices.size) + 1)
-    assert eng.last_stats["levels"] == [dict(mode="push", budget=floor,
-                                             need=0, total=0, retries=0)]
+    assert eng.last_stats["levels"] == [dict(
+        mode="push", budget=floor, need=0, total=0, retries=0,
+        owner=owner_path(g.n_pad, floor))]
     assert eng.last_stats["budget"] == floor
 
 
